@@ -11,7 +11,7 @@ import sys
 import time
 
 
-def main() -> None:
+def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default="",
                     help="comma list: table2,fig2,fig3,fig5,serving,disagg,"
@@ -51,6 +51,7 @@ def main() -> None:
         sections.append(("Roofline (dry-run artifacts)", roofline.run))
 
     print("name,value,derived")
+    failed = []
     for title, fn in sections:
         print(f"# --- {title} ---", flush=True)
         t0 = time.time()
@@ -59,8 +60,13 @@ def main() -> None:
                 print(row, flush=True)
         except Exception as e:  # noqa: BLE001
             print(f"{title}.ERROR,0,{e!r}", flush=True)
+            failed.append(title)
         print(f"# {title}: {time.time()-t0:.1f}s", flush=True)
+    if failed:
+        print(f"# {len(failed)} section(s) raised: {', '.join(failed)}",
+              file=sys.stderr, flush=True)
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    raise SystemExit(main())

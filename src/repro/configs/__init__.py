@@ -11,7 +11,7 @@ from typing import Dict, List, Optional, Tuple
 from repro.configs.base import (
     MeshConfig, ModelConfig, MoEConfig, PagedKVConfig, SSMConfig, ShapeConfig,
     TrainConfig, SINGLE_POD, MULTI_POD, model_active_params, model_params,
-    reduce_for_smoke,
+    reduce_for_smoke, with_blocks,
 )
 
 # arch id -> module name
@@ -72,5 +72,5 @@ __all__ = [
     "ARCH_IDS", "SHAPES", "MeshConfig", "ModelConfig", "MoEConfig",
     "PagedKVConfig", "SSMConfig", "ShapeConfig", "TrainConfig", "SINGLE_POD",
     "MULTI_POD", "all_cells", "cell_skip_reason", "get_config", "get_shape",
-    "model_active_params", "model_params", "reduce_for_smoke",
+    "model_active_params", "model_params", "reduce_for_smoke", "with_blocks",
 ]

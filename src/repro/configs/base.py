@@ -342,6 +342,13 @@ def reduce_for_smoke(cfg: ModelConfig) -> ModelConfig:
     return replace(cfg, **kw)
 
 
+def with_blocks(cfg: ModelConfig, n_blocks: int) -> ModelConfig:
+    """``cfg`` cut (or grown) to ``n_blocks`` repetitions of its block
+    pattern, widths untouched: a depth cut keeps whole periods."""
+    return replace(cfg, n_layers=cfg.first_k_dense
+                   + n_blocks * len(cfg.block_pattern))
+
+
 def model_params(cfg: ModelConfig) -> int:
     """Analytic parameter count (for 6*N*D roofline term)."""
     d, dff, v = cfg.d_model, cfg.d_ff, cfg.vocab_size

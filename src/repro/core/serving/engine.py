@@ -352,7 +352,7 @@ class ServingEngine:
             if _sp_mode(cfg, n_slots, max_len):
                 raise NotImplementedError(
                     "zero_copy serving does not support the SP cache layout")
-            self.null_page = n_slots * self.max_pages
+            self.null_page = self.mgr.null_page     # == device pool pages
             self.cache = init_cache(cfg, n_slots, max_len, page_size,
                                     src_len=src_len, per_seq=True,
                                     global_pages=self.null_page)
@@ -443,6 +443,10 @@ class ServingEngine:
             self._continuous_step()
         else:
             self._admit()
+            # Prefill's first token can already finish a request
+            # (max_tokens=1): release it before the decode step, which
+            # appends a token to every active request.
+            self._release_done(finished)
             self._decode_step()
         self._step_count += 1
         self._release_done(finished)
@@ -937,6 +941,23 @@ class ServingEngine:
         self.metrics["decode_steps"] += 1
         self.mgr.observe_step()
         self.metrics["decode_s"] += time.perf_counter() - t0
+
+    def decode_step_hlo(self) -> str:
+        """Optimized HLO of the zero-copy decode step at this engine's
+        shapes: what the device runs (e.g. whether the Pallas kernel is in
+        it as a ``tpu_custom_call``)."""
+        if self.offload_mode != "zero_copy":
+            raise NotImplementedError("decode_step_hlo needs zero_copy")
+        toks = jnp.zeros((self.n_slots, 1), jnp.int32)
+        kv_len = jnp.zeros((self.n_slots,), jnp.int32)
+        if self.sched is not None:
+            lowered = self._decode_m.lower(
+                self.params, toks, kv_len, self._tables_dev,
+                jnp.zeros((self.n_slots,), bool), self.cache)
+        else:
+            lowered = self._decode.lower(self.params, toks, kv_len,
+                                         self._tables_dev, self.cache)
+        return lowered.compile().as_text()
 
     def stats(self) -> dict:
         s = self.mgr.stats()

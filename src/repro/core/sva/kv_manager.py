@@ -586,25 +586,26 @@ class PagedKVManager:
         self.offload_mode = offload_mode
         self.layout = layout
         self.total_pages = n_slots * max_pages_per_slot
-        self.null_page = self.total_pages            # device drop/zero sentinel
-        # ``pool_pages`` constrains the PHYSICAL pool below the worst case
-        # (n_slots full slots) — the oversubscription regime continuous
+        # ``pool_pages`` sizes the PHYSICAL pool. Below the worst case
+        # (n_slots full slots) it is the oversubscription regime continuous
         # batching is built for: lazy admissions pack more live sequences
         # than full reservations would, and the scheduler preempts when
-        # growth outruns the pool. Device arrays keep worst-case sizing
-        # (the null page id is unchanged); the allocator just never hands
-        # out pages >= pool_pages.
+        # growth outruns the pool; device arrays keep worst-case sizing and
+        # the allocator never hands out pages >= pool_pages. Above it, the
+        # spare pages hold the warm prefix cache, and the device pool grows
+        # to match.
         if pool_pages is None:
             pool_pages = self.total_pages
         if layout == "global":
-            if not max_pages_per_slot <= pool_pages <= self.total_pages:
+            if pool_pages < max_pages_per_slot:
                 raise ValueError(
-                    f"pool_pages={pool_pages} (need max_pages_per_slot="
-                    f"{max_pages_per_slot} <= pool_pages <= "
-                    f"{self.total_pages})")
+                    f"pool_pages={pool_pages} (need >= max_pages_per_slot="
+                    f"{max_pages_per_slot})")
         elif pool_pages != self.total_pages:
             raise ValueError("pool_pages requires the global layout")
         self.pool_pages = pool_pages
+        # device drop/zero sentinel: one past the device pool's last page
+        self.null_page = max(self.total_pages, pool_pages)
         if layout == "global":
             self.pool = PagePool(pool_pages, page_size)
             self.pools = None

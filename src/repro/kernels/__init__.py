@@ -1,3 +1,24 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas TPU kernels for the compute hot-spots the paper optimizes.
+
+Every kernel takes ``interpret=None`` by default and resolves it through
+:func:`interpret_mode`: compiled on a TPU, interpreted everywhere else. No
+kernel decides this on its own, so nothing on the TPU path can silently run
+the interpreter.
+"""
+from __future__ import annotations
+
+from typing import Optional
+
+import jax
+
+
+def interpret_mode(platform: Optional[str] = None) -> bool:
+    """Whether Pallas kernels run in interpret mode on ``platform``
+    (default: JAX's default backend). False only on a TPU."""
+    return (platform or jax.default_backend()) != "tpu"
+
+
+def resolve_interpret(interpret: Optional[bool]) -> bool:
+    """A kernel's ``interpret`` argument: an explicit bool wins, None
+    follows the platform."""
+    return interpret_mode() if interpret is None else bool(interpret)

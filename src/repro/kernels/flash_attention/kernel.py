@@ -14,6 +14,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 
 def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
             bq: int, bkv: int, n_kv: int, causal: bool, softcap,
@@ -66,7 +68,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 def flash_attention_fwd(q, k, v, *, causal: bool = True, softcap=None,
                         bq: int = 128, bkv: int = 128,
-                        interpret: bool = True):
+                        interpret: bool | None = None):
     """q, k, v: (B, H, S, D) with H == Hkv (pre-repeated). -> (B, H, S, D)."""
     B, H, S, D = q.shape
     Skv = k.shape[2]
@@ -94,5 +96,5 @@ def flash_attention_fwd(q, k, v, *, causal: bool = True, softcap=None,
             pltpu.VMEM((bq, D), jnp.float32),
         ],
         out_shape=jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)

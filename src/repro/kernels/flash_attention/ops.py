@@ -10,7 +10,7 @@ from repro.kernels.flash_attention.ref import attention_ref
 @functools.partial(jax.jit, static_argnames=("causal", "softcap", "bq", "bkv",
                                              "interpret", "use_pallas"))
 def flash_attention_op(q, k, v, *, causal=True, softcap=None, bq=128,
-                       bkv=128, interpret=True, use_pallas=True):
+                       bkv=128, interpret=None, use_pallas=True):
     """q: (B,S,Hq,D); k,v: (B,S,Hkv,D) — BSHD layout like models/attention."""
     B, S, Hq, D = q.shape
     Hkv = k.shape[2]

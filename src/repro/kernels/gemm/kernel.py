@@ -15,6 +15,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 
 def _kernel(a_ref, b_ref, o_ref, acc_ref, *, n_k: int):
     k = pl.program_id(2)
@@ -33,7 +35,7 @@ def _kernel(a_ref, b_ref, o_ref, acc_ref, *, n_k: int):
 
 
 def gemm(a: jax.Array, b: jax.Array, *, bm: int = 128, bn: int = 128,
-         bk: int = 128, interpret: bool = True) -> jax.Array:
+         bk: int = 128, interpret: bool | None = None) -> jax.Array:
     """a: (M, K) @ b: (K, N) -> (M, N)."""
     M, K = a.shape
     _, N = b.shape
@@ -50,5 +52,5 @@ def gemm(a: jax.Array, b: jax.Array, *, bm: int = 128, bn: int = 128,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         out_shape=jax.ShapeDtypeStruct((M, N), a.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(a, b)

@@ -12,6 +12,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+
 
 def _kernel(ab_ref, a_ref, b_ref, x_ref, o_ref):
     alpha, beta = ab_ref[0], ab_ref[1]
@@ -23,7 +25,7 @@ def _kernel(ab_ref, a_ref, b_ref, x_ref, o_ref):
     o_ref[...] = (alpha * ya + beta * yb).astype(o_ref.dtype)
 
 
-def gesummv(alpha, beta, a, b, x, *, bm: int = 128, interpret: bool = True):
+def gesummv(alpha, beta, a, b, x, *, bm: int = 128, interpret: bool | None = None):
     N, K = a.shape
     bm = min(bm, N)
     while N % bm:
@@ -41,5 +43,5 @@ def gesummv(alpha, beta, a, b, x, *, bm: int = 128, interpret: bool = True):
         ],
         out_specs=pl.BlockSpec((bm,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((N,), x.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(ab, a, b, x)

@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+
 
 def _bitonic_block(x: jax.Array) -> jax.Array:
     """Fully-unrolled bitonic sort of a (rows, n) block along axis 1 (asc)."""
@@ -44,7 +46,7 @@ def _kernel(x_ref, o_ref):
 
 
 def block_sort(x: jax.Array, *, block: int = 1024,
-               interpret: bool = True) -> jax.Array:
+               interpret: bool | None = None) -> jax.Array:
     """Sort contiguous blocks of a (n,) array (n, block powers of two)."""
     n = x.shape[0]
     block = min(block, n)
@@ -56,6 +58,6 @@ def block_sort(x: jax.Array, *, block: int = 1024,
         in_specs=[pl.BlockSpec((1, block), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((1, block), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(xb.shape, x.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(xb)
     return out.reshape(n)

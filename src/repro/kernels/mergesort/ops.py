@@ -25,7 +25,7 @@ def _global_stage(x, j):
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret",
                                              "use_pallas"))
-def mergesort(x, *, block: int = 1024, interpret: bool = True,
+def mergesort(x, *, block: int = 1024, interpret=None,
               use_pallas: bool = True):
     """Ascending sort of a power-of-two length array."""
     if not use_pallas:

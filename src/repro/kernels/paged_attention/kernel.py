@@ -42,6 +42,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 
 def _kernel(table_ref, len_ref,        # scalar-prefetch (SMEM)
             q_ref, k_ref, v_ref,       # VMEM blocks
@@ -57,19 +59,26 @@ def _kernel(table_ref, len_ref,        # scalar-prefetch (SMEM)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0]                               # (Hq, D)
-    # KV block: (1, 1, page, Hkv, D) per-slot, (1, page, Hkv, D) global —
-    # same page once the leading singleton block dims are dropped.
-    k = k_ref[...].reshape(k_ref.shape[-3:])   # (page, Hkv, D)
-    v = v_ref[...].reshape(v_ref.shape[-3:])
+    q = q_ref[0].astype(jnp.float32)           # (Hq, D)
     Hq, D = q.shape
-    Hkv = k.shape[1]
+    Hkv = k_ref.shape[-2]
     G = Hq // Hkv
-
-    # scores: (Hq, page) — each q head attends its kv group's head
-    kg = jnp.repeat(k, G, axis=1)              # (page, Hq, D)
-    s = jnp.einsum("hd,phd->hp", q.astype(jnp.float32),
-                   kg.astype(jnp.float32))
+    # KV block: (1, 1, page, Hkv, D) per-slot, (1, page, Hkv, D) global —
+    # the same page once the leading singleton block dims are indexed away.
+    lead = (0,) * (k_ref.ndim - 3)
+    # q head h attends KV head h // G. Every contraction is a 2-D dot per
+    # KV head: all Hq query rows against that head's (page, D) keys, keeping
+    # the rows of its group. The extra rows cost MXU work only; a batched
+    # dot over heads does not lower on the TPU.
+    group = jax.lax.broadcasted_iota(jnp.int32, (Hq, 1), 0) // G
+    hi = jax.lax.Precision.HIGHEST
+    s = jnp.zeros((Hq, page), jnp.float32)
+    for h in range(Hkv):
+        kh = k_ref[lead + (slice(None), h, slice(None))].astype(jnp.float32)
+        sh = jax.lax.dot_general(q, kh, (((1,), (1,)), ((), ())),
+                                 precision=hi,
+                                 preferred_element_type=jnp.float32)
+        s = jnp.where(group == h, sh, s)       # (Hq, page)
     s = s * (D ** -0.5)
     if softcap is not None:
         s = softcap * jnp.tanh(s / softcap)
@@ -77,6 +86,7 @@ def _kernel(table_ref, len_ref,        # scalar-prefetch (SMEM)
     length = len_ref[b]
     pos = p * page + jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)
     valid = pos < length
+    # mask before the max: stale KV in a recycled page must not move m
     s = jnp.where(valid, s, -jnp.inf)
 
     m_prev, l_prev = m_ref[...], l_ref[...]    # (Hq, 1)
@@ -88,20 +98,24 @@ def _kernel(table_ref, len_ref,        # scalar-prefetch (SMEM)
     corr = jnp.where(jnp.isfinite(m_prev),
                      jnp.exp(m_prev - m_safe), 0.0)
     l_new = l_prev * corr + jnp.sum(p_, axis=-1, keepdims=True)
-    vg = jnp.repeat(v, G, axis=1).astype(jnp.float32)   # (page, Hq, D)
-    pv = jnp.einsum("hp,phd->hd", p_, vg)
-    acc_ref[...] = acc_ref[...] * corr[..., None] + pv[:, None, :]
+    pv = jnp.zeros((Hq, D), jnp.float32)
+    for h in range(Hkv):
+        vh = v_ref[lead + (slice(None), h, slice(None))].astype(jnp.float32)
+        pvh = jnp.dot(p_, vh, precision=hi,
+                      preferred_element_type=jnp.float32)
+        pv = jnp.where(group == h, pvh, pv)   # (Hq, D)
+    acc_ref[...] = acc_ref[...] * corr + pv
     m_ref[...], l_ref[...] = m_new, l_new
 
     @pl.when(p == n_pages - 1)
     def _finish():
         l = jnp.maximum(l_ref[...], 1e-20)
-        o_ref[0] = (acc_ref[...][:, 0] / l).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 def paged_attention(q, k_pool, v_pool, block_table, lengths, *,
                     softcap=None, table_residency: str = "smem",
-                    interpret: bool = True):
+                    interpret: bool | None = None):
     """See module docstring. Returns (B, Hq, D)."""
     B, Hq, D = q.shape
     _, n_pages, page, Hkv, _ = k_pool.shape
@@ -136,20 +150,20 @@ def paged_attention(q, k_pool, v_pool, block_table, lengths, *,
         scratch_shapes=[
             pltpu.VMEM((Hq, 1), jnp.float32),
             pltpu.VMEM((Hq, 1), jnp.float32),
-            pltpu.VMEM((Hq, 1, D), jnp.float32),
+            pltpu.VMEM((Hq, D), jnp.float32),
         ],
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hq, D), q.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(block_table, lengths, q, k_pool, v_pool)
 
 
 def paged_attention_global(q, k_pool, v_pool, block_table, lengths, *,
                            softcap=None, table_residency: str = "smem",
-                           interpret: bool = True):
+                           interpret: bool | None = None):
     """Decode attention over the GLOBAL (shared-pool) layout — see module
     docstring. q: (B, Hq, D); pools: (total, page, Hkv, D); table: (B, P)
     int32 with NULL (>= total) marking unallocated entries. Returns
@@ -193,12 +207,12 @@ def paged_attention_global(q, k_pool, v_pool, block_table, lengths, *,
         scratch_shapes=[
             pltpu.VMEM((Hq, 1), jnp.float32),
             pltpu.VMEM((Hq, 1), jnp.float32),
-            pltpu.VMEM((Hq, 1, D), jnp.float32),
+            pltpu.VMEM((Hq, D), jnp.float32),
         ],
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hq, D), q.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(block_table, lengths, q, k_pool, v_pool)

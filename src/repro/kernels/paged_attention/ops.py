@@ -20,7 +20,7 @@ from repro.kernels.paged_attention.ref import (paged_attention_global_ref,
 @functools.partial(jax.jit, static_argnames=("softcap", "table_residency",
                                              "interpret", "use_pallas"))
 def paged_decode(q, k_pool, v_pool, block_table, lengths, *, softcap=None,
-                 table_residency: str = "smem", interpret: bool = True,
+                 table_residency: str = "smem", interpret=None,
                  use_pallas: bool = True):
     is_global = k_pool.ndim == 4
     if not use_pallas:

@@ -32,7 +32,7 @@ import numpy as np
 from repro.configs import (SHAPES, TrainConfig, all_cells, cell_skip_reason,
                            get_config, get_shape)
 from repro.launch.input_specs import input_specs
-from repro.launch.mesh import make_production_mesh, mesh_context
+from repro.launch.mesh import make_production_mesh
 from repro.launch.steps import make_step
 from repro.models import MeshInfo
 from repro.models.params import abstract
@@ -82,7 +82,7 @@ def compile_cell(cfg, shape, mesh, tc=None, donate_cache=True):
     ins = input_specs(cfg, shape, mesh)
 
     t0 = time.time()
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             jitted = jax.jit(step, donate_argnums=(0, 1))
             lowered = jitted.lower(state_abs["params"], state_abs["opt_state"],
@@ -101,8 +101,6 @@ def compile_cell(cfg, shape, mesh, tc=None, donate_cache=True):
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):        # older JAX: one dict per program
-        cost = cost[0] if cost else {}
     hlo = compiled.as_text()
     colls = parse_collectives(hlo)
     art = {

@@ -4,41 +4,25 @@ A FUNCTION, not a module-level constant: importing this module never touches
 jax device state. ``dryrun.py`` sets XLA_FLAGS for 512 placeholder devices
 *before* any jax import; everything else sees the real device count.
 
-Version compatibility: ``jax.sharding.AxisType`` / ``jax.set_mesh`` only
-exist in newer JAX releases. On older versions (e.g. 0.4.37) meshes are
-built without explicit axis types — every sharding in this codebase is an
-explicit NamedSharding, so no ambient mesh is required — and
-``mesh_context`` degrades to a no-op context manager.
+Meshes use Auto axes (``jax.make_mesh`` defaults to Explicit): every
+sharding in this codebase is an explicit NamedSharding or a
+``with_sharding_constraint``, and the partitioner propagates the rest.
+Callers enter a mesh with ``jax.set_mesh``.
 """
 from __future__ import annotations
 
-import contextlib
 from typing import Optional, Tuple
 
 import jax
 
-from repro.configs.base import MeshConfig, MULTI_POD, SINGLE_POD
-
-_HAS_AXIS_TYPES = hasattr(jax.sharding, "AxisType")
-
-
-def _axis_type_kwargs(n_axes: int) -> dict:
-    if _HAS_AXIS_TYPES:
-        return {"axis_types": (jax.sharding.AxisType.Auto,) * n_axes}
-    return {}
+from repro.configs.base import MeshConfig
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]
               ) -> jax.sharding.Mesh:
-    """Version-portable ``jax.make_mesh`` (explicit Auto axes when supported)."""
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
-
-
-def mesh_context(mesh: jax.sharding.Mesh):
-    """``jax.set_mesh(mesh)`` where it exists, else a no-op context."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return contextlib.nullcontext(mesh)
+    """``jax.make_mesh`` with Auto axis types."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:

@@ -37,11 +37,7 @@ def pipeline_apply(block_params, x_micro, apply_stage: Callable, mesh,
     def per_stage(p_loc, xs):
         s = jax.lax.axis_index(stage_axis)
         # carries become stage-varying after the first ppermute; mark them so
-        # (older JAX has no varying-manual-axes tracking: identity there)
-        if hasattr(jax.lax, "pcast"):
-            varying = lambda v: jax.lax.pcast(v, (stage_axis,), to="varying")
-        else:
-            varying = lambda v: v
+        varying = lambda v: jax.lax.pcast(v, (stage_axis,), to="varying")
         zero = varying(jnp.zeros_like(xs[0]))
         outs0 = varying(jnp.zeros_like(xs))
         xs = varying(xs)
@@ -76,8 +72,7 @@ def pipeline_apply(block_params, x_micro, apply_stage: Callable, mesh,
         return outs
 
     in_block_spec = jax.tree.map(lambda _: P(stage_axis), block_params)
-    from repro.models.dist import shard_map
-    return shard_map(
+    return jax.shard_map(
         per_stage, mesh=mesh,
         in_specs=(in_block_spec, P()),
         out_specs=P(),

@@ -22,9 +22,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.checkpoint.ckpt import latest_step, restore, save
-from repro.configs import TrainConfig, get_config, reduce_for_smoke
+from repro.configs import (TrainConfig, get_config, reduce_for_smoke,
+                           with_blocks)
 from repro.data.pipeline import make_pipeline
-from repro.launch.mesh import make_host_mesh, mesh_context
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_host_mesh
 from repro.launch.steps import make_train_step
 from repro.models import MeshInfo, NO_MESH, init_params, model_specs
 from repro.models.params import shardings as spec_shardings
@@ -38,8 +40,7 @@ def build(args):
     if args.smoke:
         cfg = reduce_for_smoke(cfg)
     if args.layers:
-        cfg = dataclasses.replace(
-            cfg, n_layers=cfg.first_k_dense + args.layers * len(cfg.block_pattern))
+        cfg = with_blocks(cfg, args.layers)
     if args.d_model:
         head = max(args.d_model // max(cfg.n_heads, 1), 8)
         # scale_embeddings: from-scratch stability — with 0.02-init embeddings
@@ -73,6 +74,7 @@ def main(argv=None) -> int:
     ap.add_argument("--log-every", type=int, default=1)
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = build(args)
     tc = TrainConfig(lr=args.lr, total_steps=args.steps,
                      warmup_steps=max(args.steps // 10, 1),
@@ -92,7 +94,7 @@ def main(argv=None) -> int:
     executor = ThreadPoolExecutor(max_workers=1)
     step_fn = make_train_step(cfg, tc, mi)
     if mesh is not None:
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             step_fn = jax.jit(step_fn, donate_argnums=(0, 1))
     else:
         step_fn = jax.jit(step_fn, donate_argnums=(0, 1))

@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.models.dist import shard_map
 from repro.models.params import ParamSpec
 
 
@@ -360,10 +359,10 @@ def paged_decode_attention(q: jax.Array, kv: PagedKV, *,
     ``backend="pallas"`` routes the step through the scalar-prefetch Pallas
     kernel (kernels/paged_attention): the block table lives in SMEM and
     drives the KV page DMAs directly — the paper's PTW-in-LLC realized on
-    the serving hot path (interpret-mode off-TPU, compiled kernel on TPU).
-    Both PagedKV layouts are supported; rare shapes the kernel does not
-    cover (leading stacked-blocks axis outside a scan) fall back to the
-    pure-JAX path.
+    the serving hot path (compiled on a TPU, interpret mode elsewhere; see
+    ``repro.kernels.interpret_mode``). Both PagedKV layouts are supported;
+    a table with a leading stacked-blocks axis (outside the layer scan) is
+    refused rather than served by the pure-JAX path.
 
     Sliding-window layers use a pool whose capacity equals the window; the
     rolling write in ``paged_append`` makes every slot valid once
@@ -384,13 +383,17 @@ def paged_decode_attention(q: jax.Array, kv: PagedKV, *,
     map-don't-copy insight applied to the kernel's own data movement).
     """
     B, _, Hq, D = q.shape
-    if backend == "pallas" and kv.block_table.ndim == 2:
+    if backend == "pallas":
+        if kv.block_table.ndim != 2:
+            raise ValueError(
+                "decode_backend='pallas' serves one layer's (B, P) block "
+                f"table; got a table of shape {kv.block_table.shape} (a "
+                "leading stacked-blocks axis belongs inside the layer scan)")
         from repro.kernels.paged_attention.ops import paged_decode
         lengths = jnp.broadcast_to(kv.length, (B,)).astype(jnp.int32)
-        interpret = jax.default_backend() != "tpu"
         out = paged_decode(q[:, 0], kv.k_pool, kv.v_pool,
                            kv.block_table.astype(jnp.int32), lengths,
-                           softcap=softcap, interpret=interpret)
+                           softcap=softcap)
         return out[:, None].astype(q.dtype)
     if is_global_layout(kv):
         # GLOBAL POOL: each sequence sees its pages in LOGICAL order through
@@ -610,7 +613,7 @@ def sp_paged_decode(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
     # shard over 'data'. GQA head counts rarely divide the model axis.
     pool_spec = P(None, seq_axis, None, None, None)
     head_spec = P(None, None, None, None)
-    out, kp, vp = shard_map(
+    out, kp, vp = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(head_spec, head_spec, head_spec, pool_spec, pool_spec,
                   P(None, seq_axis), P()),

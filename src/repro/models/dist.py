@@ -12,13 +12,7 @@ from typing import Optional, Tuple
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.models.params import logical_to_mesh, resolve_spec
-
-# jax.shard_map only exists in newer JAX; fall back to the experimental home.
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map  # noqa: F401
+from repro.models.params import resolve_spec
 
 
 @dataclass(frozen=True)

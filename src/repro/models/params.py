@@ -16,6 +16,7 @@ memory behavior fall out of pure annotations.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from dataclasses import dataclass
 from typing import Any, Callable, Optional, Tuple
 
@@ -120,23 +121,34 @@ def shardings(tree, mesh):
             mesh, resolve_spec(s.pspec, s.shape, mesh)), tree)
 
 
+@functools.partial(jax.jit, static_argnums=0)
+def _init_leaf(s: ParamSpec, k: jax.Array) -> jax.Array:
+    """One leaf, built on the default device in its own dtype.
+
+    A narrower leaf fuses its float32 draw into the cast, so no float32 copy
+    of it is ever held; fusion reorders the draw's scaling, which moves about
+    six in a million bfloat16 values by one ulp against an unfused draw. A
+    float32 leaf has no temporary to save, and a barrier keeps it
+    bit-identical to the unfused draw for the same key."""
+    if s.init == "zeros":
+        return jnp.zeros(s.shape, s.dtype)
+    if s.init == "ones":
+        return jnp.ones(s.shape, s.dtype)
+    scale = s.scale if s.scale is not None else s.fan_in() ** -0.5
+    if s.init == "uniform_pm":
+        return jax.random.uniform(k, s.shape, jnp.float32, -scale, scale).astype(s.dtype)
+    x = jax.random.normal(k, s.shape, jnp.float32)
+    if jnp.dtype(s.dtype) == jnp.float32:
+        x = jax.lax.optimization_barrier(x)
+    return (x * scale).astype(s.dtype)
+
+
 def materialize(tree, key: jax.Array):
     """Allocate real parameters (used for smoke tests and real training)."""
     leaves, treedef = jax.tree_util.tree_flatten(tree, is_leaf=is_spec)
     keys = jax.random.split(key, len(leaves))
-
-    def mk(s: ParamSpec, k):
-        if s.init == "zeros":
-            return jnp.zeros(s.shape, s.dtype)
-        if s.init == "ones":
-            return jnp.ones(s.shape, s.dtype)
-        scale = s.scale if s.scale is not None else s.fan_in() ** -0.5
-        if s.init == "uniform_pm":
-            return jax.random.uniform(k, s.shape, jnp.float32, -scale, scale).astype(s.dtype)
-        return (jax.random.normal(k, s.shape, jnp.float32) * scale).astype(s.dtype)
-
     return jax.tree_util.tree_unflatten(
-        treedef, [mk(s, k) for s, k in zip(leaves, keys)])
+        treedef, [_init_leaf(s, k) for s, k in zip(leaves, keys)])
 
 
 def count_params(tree) -> int:

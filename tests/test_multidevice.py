@@ -24,7 +24,7 @@ def test_pipeline_parallel_fwd_grad():
     _run("""
         import jax, jax.numpy as jnp
         from repro.launch.pipeline import pipeline_apply
-        from repro.launch.mesh import make_mesh, mesh_context
+        from repro.launch.mesh import make_mesh
         mesh = make_mesh((4,), ("stage",))
         W = jax.random.normal(jax.random.key(0), (8, 16, 16)) * 0.3
         x = jax.random.normal(jax.random.key(1), (6, 2, 4, 16))
@@ -36,7 +36,7 @@ def test_pipeline_parallel_fwd_grad():
                 return jax.lax.scan(lambda c, w: (jnp.tanh(c @ w), None),
                                     xx, Wp)[0]
             return jnp.sum(jnp.sin(jax.vmap(one)(x)))
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             out = jax.jit(lambda W, x: pipeline_apply(
                 W, x, apply_stage, mesh))(W, x)
             g1 = jax.jit(jax.grad(lambda Wp: jnp.sum(jnp.sin(
@@ -56,14 +56,13 @@ def test_compressed_psum_close_to_exact():
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
         from repro.optim.compression import compressed_psum
-        from repro.launch.mesh import make_mesh, mesh_context
-        from repro.models.dist import shard_map
+        from repro.launch.mesh import make_mesh
         mesh = make_mesh((4,), ("data",))
         x = jax.random.normal(jax.random.key(0), (4, 256))
         def f(x):
             return compressed_psum(x, "data"), jax.lax.psum(x, "data")
-        with mesh_context(mesh):
-            got, exact = jax.jit(shard_map(
+        with jax.set_mesh(mesh):
+            got, exact = jax.jit(jax.shard_map(
                 f, mesh=mesh, in_specs=P("data"),
                 out_specs=(P("data"), P("data"))))(x)
         rel = float(jnp.max(jnp.abs(got - exact))) / float(jnp.max(jnp.abs(exact)))
@@ -76,7 +75,7 @@ def test_sharded_train_step_matches_single_device():
     _run("""
         import jax, jax.numpy as jnp
         from repro.configs import TrainConfig, get_config, reduce_for_smoke
-        from repro.launch.mesh import make_host_mesh, mesh_context
+        from repro.launch.mesh import make_host_mesh
         from repro.launch.steps import make_train_step
         from repro.models import MeshInfo, NO_MESH, init_params
         from repro.optim import init_opt_state
@@ -93,7 +92,7 @@ def test_sharded_train_step_matches_single_device():
         # 2x2 mesh
         mesh = make_host_mesh(data=2, model=2)
         s2 = make_train_step(cfg, tc, MeshInfo(mesh))
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             p2, o2, m2 = jax.jit(s2)(params, init_opt_state(params), batch)
         assert abs(float(m1["loss"]) - float(m2["loss"])) < 1e-4
         d = max(float(jnp.max(jnp.abs(a - b)))
@@ -109,7 +108,7 @@ def test_sp_decode_long_context():
         from jax.sharding import PartitionSpec as P
         from repro.models.attention import PagedKV, sp_paged_decode
         from repro.models.attention import paged_decode_attention, paged_append
-        from repro.launch.mesh import make_mesh, mesh_context
+        from repro.launch.mesh import make_mesh
         mesh = make_mesh((4, 1), ("data", "model"))
         B, Hq, Hkv, P_, T, D = 1, 4, 2, 8, 4, 16
         ks = jax.random.split(jax.random.key(0), 5)
@@ -124,7 +123,7 @@ def test_sp_decode_long_context():
         # reference on one device: append + dense paged attention
         kv_ref = paged_append(kv, kn, vn)
         ref = paged_decode_attention(q, kv_ref)
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             out, kv2 = jax.jit(lambda q, kn, vn, kv: sp_paged_decode(
                 q, kn, vn, kv, mesh))(q, kn, vn, kv)
         err = float(jnp.max(jnp.abs(out - ref)))

@@ -68,11 +68,15 @@ def test_pool_pages_validation():
     with pytest.raises(ValueError):
         PagedKVManager(n_slots=2, max_pages_per_slot=4, page_size=8,
                        pool_pages=3)      # < max_pages_per_slot
-    with pytest.raises(ValueError):
-        PagedKVManager(n_slots=2, max_pages_per_slot=4, page_size=8,
-                       pool_pages=9)      # > n_slots * max_pages_per_slot
+    # > n_slots * max_pages_per_slot: the spare pages hold the warm prefix
+    # cache, and the device sentinel moves past the larger pool
+    big = PagedKVManager(n_slots=2, max_pages_per_slot=4, page_size=8,
+                         pool_pages=9)
+    assert big.pool.n_pages == 9 and big.null_page == 9
+    assert (big.tables == 9).all()
     mgr = PagedKVManager(n_slots=2, max_pages_per_slot=4, page_size=8,
                          pool_pages=5)
+    assert mgr.null_page == 8             # device arrays keep worst case
     assert mgr.pool.n_pages == 5
     # a request that fits a slot but not the shrunken pool is rejected
     with pytest.raises(Exception):
@@ -134,6 +138,33 @@ def test_interleaving_bit_identity(setup, arrivals):
     assert cont == ref
 
 
+def _check_interleaving(reqs, seed):
+    """(prompt_len, max_tokens, arrival_gap) requests: the pool-constrained
+    continuous engine is bit-identical to the fixed engine on them."""
+    import jax
+    cfg = reduce_for_smoke(get_config("llama3.2-1b"))
+    params = init_params(cfg, jax.random.key(0))
+    rng = np.random.default_rng(seed)
+    prompts = tuple(tuple(rng.integers(0, cfg.vocab_size,
+                                       size=n).tolist())
+                    for n, _, _ in reqs)
+    maxtoks = tuple(m for _, m, _ in reqs)
+    arrivals = tuple(np.cumsum([g for _, _, g in reqs]).tolist())
+    ref, _, _ = serve(cfg, params, "fixed", Workload(prompts, maxtoks))
+    cont, _, _ = serve(cfg, params, "continuous",
+                       Workload(prompts, maxtoks, arrivals=arrivals),
+                       pool_pages=POOL)
+    assert cont == ref
+    assert [len(t) for t in ref] == list(maxtoks)
+
+
+# Shrunk examples the property once found, pinned: a 1-token prompt with
+# max_tokens=1 is finished by its prefill alone.
+@pytest.mark.parametrize("reqs,seed", [([(1, 1, 0)], 0)])
+def test_interleaving_pinned_cases(reqs, seed):
+    _check_interleaving(reqs, seed)
+
+
 if HAVE_HYPOTHESIS:
     @settings(max_examples=5, deadline=None)
     @given(st.lists(st.tuples(st.integers(1, 14), st.integers(1, 6),
@@ -144,20 +175,7 @@ if HAVE_HYPOTHESIS:
         """Any (prompt_len, max_tokens, arrival_gap) interleaving: the
         pool-constrained continuous engine is bit-identical to the fixed
         engine on the same requests."""
-        import jax
-        cfg = reduce_for_smoke(get_config("llama3.2-1b"))
-        params = init_params(cfg, jax.random.key(0))
-        rng = np.random.default_rng(seed)
-        prompts = tuple(tuple(rng.integers(0, cfg.vocab_size,
-                                           size=n).tolist())
-                        for n, _, _ in reqs)
-        maxtoks = tuple(m for _, m, _ in reqs)
-        arrivals = tuple(np.cumsum([g for _, _, g in reqs]).tolist())
-        ref, _, _ = serve(cfg, params, "fixed", Workload(prompts, maxtoks))
-        cont, _, _ = serve(cfg, params, "continuous",
-                           Workload(prompts, maxtoks, arrivals=arrivals),
-                           pool_pages=POOL)
-        assert cont == ref
+        _check_interleaving(reqs, seed)
 
 
 # --------------------------------------------------- jit-cache boundedness
