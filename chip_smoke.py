@@ -1,12 +1,13 @@
 """Smoke run of the served path on one TPU chip.
 
-  python chip_smoke.py [--seed 0] [--layers 14]
+  python chip_smoke.py [--seed 0]
 
 One process, no fallback: it exits non-zero, and prints no result, unless
 JAX's first device is a TPU. It builds qwen2-7b at its published widths with
-only the depth cut (28 layers' weights plus the page pool exceed one v5e's
-HBM), makes the weights from ``--seed`` on the device, and serves seeded
-requests through the engine ``launch/serve.py`` builds: the continuous
+only the depth cut, to the bring-up depth of 14 of 28 layers (28 layers'
+weights plus the page pool exceed one v5e's HBM), makes the weights from
+``--seed`` on the device, and serves seeded requests through the engine
+``launch/serve.py`` builds: the continuous
 scheduler over the global zero-copy page pool. It serves them twice, with
 the pure-JAX decode attention and with the Pallas paged-decode kernel, then
 checks the kernel against a float32 reference on the live pool, tables and
@@ -30,16 +31,23 @@ import numpy as np
 sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent / "src"))
 
 ARCH = "qwen2-7b"
+LAYERS = 14          # of 28: the bring-up depth, not a measured limit
+REQUESTS = 8
+MAX_TOKENS = 32
 SLOTS = 8
 MAX_LEN = 2048
 PAGE = 16
 POOL_TOKENS = 65536
 PROMPT_LENS = (128, 1024)
 # Kernel vs reference: both read the same bf16 pool and compute in float32,
-# so they differ only by summation order and exp rounding (~1e-6 relative
-# over <= 2048 keys). 1e-4 leaves ~100x headroom yet sits far below one
-# bf16 ulp at 1.0 (7.8e-3): a kernel that dropped to bf16 arithmetic, or
-# read a wrong page, fails it.
+# so they differ by summation order and exp rounding (~1e-6 relative over
+# <= 2048 keys). q holds bf16 values, so q.k is exact even in one bf16 pass:
+# the limit cannot see that dot's precision. allclose allows
+# atol + rtol * |ref| per element, up to ~1.2e-2 where |ref| reaches ~121
+# (random weights, PERF.md). What fails it is a wrong page read, or the
+# probabilities rounded to bf16 in the PV product: the served pure-JAX
+# decode attention at TPU default precision does that, and ``main`` asserts
+# that this control fails the limit.
 KERNEL_TOL = 1e-4
 
 
@@ -103,10 +111,14 @@ def serve(eng, prompts, max_tokens: int, *, snapshot: bool = False):
     return [finished[r].out_tokens for r in rids], snap
 
 
+def within_tol(out, ref) -> bool:
+    return bool(np.allclose(out, ref, rtol=KERNEL_TOL, atol=KERNEL_TOL))
+
+
 def kernel_check(snap, n_heads: int, seed: int) -> dict:
     """Pallas paged decode on a live pool vs the float32 reference, on the
-    decoding slots. Also reports, unchecked, how far the served pure-JAX
-    decode attention lands from the same reference at default precision."""
+    decoding slots. Also reports how far the served pure-JAX decode
+    attention, at default precision, lands from the same reference."""
     import jax.numpy as jnp
     from repro.kernels.paged_attention.ops import paged_decode
     from repro.kernels.paged_attention.ref import paged_attention_global_ref
@@ -130,9 +142,10 @@ def kernel_check(snap, n_heads: int, seed: int) -> dict:
     ref = np.asarray(ref)[rows]
     assert np.all(np.isfinite(out)), "kernel output is not finite"
     np.testing.assert_allclose(out, ref, rtol=KERNEL_TOL, atol=KERNEL_TOL)
+    served_jax = np.asarray(served_jax)[rows]
     return {"err": float(np.max(np.abs(out - ref))),
-            "jax_err": float(np.max(np.abs(
-                np.asarray(served_jax)[rows] - ref))),
+            "jax_err": float(np.max(np.abs(served_jax - ref))),
+            "jax_within": within_tol(served_jax, ref),
             "scale": float(np.max(np.abs(ref))), "rows": int(rows.sum())}
 
 
@@ -151,10 +164,6 @@ def check_lengths(outs, max_tokens: int) -> None:
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--layers", type=int, default=14,
-                    help="decoder layers kept of qwen2-7b's 28")
-    ap.add_argument("--requests", type=int, default=8)
-    ap.add_argument("--max-tokens", type=int, default=32)
     args = ap.parse_args(argv)
 
     dev = require_tpu()
@@ -168,7 +177,7 @@ def main(argv=None) -> int:
     log(f"compile cache: {enable_compile_cache()}")
 
     full = get_config(ARCH)
-    cfg = build_config(ARCH, layers=args.layers)
+    cfg = build_config(ARCH, layers=LAYERS)
     pool_pages = POOL_TOKENS // PAGE
     kv_token_bytes = (2 * cfg.n_kv_heads * cfg.d_head
                       * np.dtype(cfg.activation_dtype).itemsize)
@@ -179,8 +188,8 @@ def main(argv=None) -> int:
     log(f"model: {ARCH} d_model={cfg.d_model} heads={cfg.n_heads}/"
         f"{cfg.n_kv_heads}x{cfg.d_head} d_ff={cfg.d_ff} "
         f"vocab={cfg.vocab_size} (published widths)")
-    log(f"depth cut: {cfg.n_layers} of {full.n_layers} layers; reason: "
-        f"{full.n_layers} layers + {POOL_TOKENS}-token pool need "
+    log(f"depth cut: {cfg.n_layers} of {full.n_layers} layers, the bring-up "
+        f"depth; {full.n_layers} layers + {POOL_TOKENS}-token pool need "
         f"{full_bytes / gib:.2f} GiB, device bytes_limit "
         f"{limit / gib:.2f} GiB")
     log(f"engine: scheduler=continuous offload=zero_copy slots={SLOTS} "
@@ -192,10 +201,10 @@ def main(argv=None) -> int:
     log(f"init params: {param_bytes(model_specs(cfg)) / gib:.2f} GiB in "
         f"{time.perf_counter() - t0:.3f} s wall on {kind} (compiles included)")
 
-    prompts = make_prompts(args.requests, cfg.vocab_size, PROMPT_LENS,
+    prompts = make_prompts(REQUESTS, cfg.vocab_size, PROMPT_LENS,
                            args.seed)
     log(f"requests: {len(prompts)} prompts of {min(map(len, prompts))}-"
-        f"{max(map(len, prompts))} tokens, max_tokens={args.max_tokens}")
+        f"{max(map(len, prompts))} tokens, max_tokens={MAX_TOKENS}")
 
     outs = {}
     for backend in ("jax", "pallas"):
@@ -203,13 +212,13 @@ def main(argv=None) -> int:
                            page_size=PAGE, pool_pages=pool_pages,
                            decode_backend=backend)
         t0 = time.perf_counter()
-        outs[backend], snap = serve(eng, prompts, args.max_tokens,
+        outs[backend], snap = serve(eng, prompts, MAX_TOKENS,
                                     snapshot=backend == "pallas")
         wall = time.perf_counter() - t0
-        check_lengths(outs[backend], args.max_tokens)
+        check_lengths(outs[backend], MAX_TOKENS)
         s = eng.stats()
         log(f"serve[{backend}]: {len(prompts)} requests x "
-            f"{args.max_tokens} tokens, scheduler={eng.scheduler_mode}; "
+            f"{MAX_TOKENS} tokens, scheduler={eng.scheduler_mode}; "
             f"output tokens={sum(map(len, outs[backend]))} "
             f"decode tokens={s['tokens']} decode_steps={s['decode_steps']} "
             f"prefills={s['prefills']} staging_copies={s['staging_copies']} "
@@ -236,8 +245,12 @@ def main(argv=None) -> int:
     log(f"kernel vs float32 reference on the live pool (layer 0, "
         f"{kc['rows']} decoding slots): max abs err {kc['err']:.3e} (max "
         f"|ref| {kc['scale']:.3e}), tolerance rtol=atol={KERNEL_TOL}; "
-        f"served pure-JAX decode attention at default precision: max abs "
-        f"err {kc['jax_err']:.3e} (not a check)")
+        f"control, the served pure-JAX decode attention at default "
+        f"precision: max abs err {kc['jax_err']:.3e}, within tolerance: "
+        f"{kc['jax_within']}")
+    assert not kc["jax_within"], \
+        "the default-precision control passes the kernel tolerance: the " \
+        "check cannot tell bf16 PV rounding from float32"
 
     peak = (dev.memory_stats() or {}).get("peak_bytes_in_use")
     log(f"peak_bytes_in_use: {peak} ({(peak or 0) / gib:.2f} GiB) on {kind}")

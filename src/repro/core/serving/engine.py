@@ -946,17 +946,13 @@ class ServingEngine:
         """Optimized HLO of the zero-copy decode step at this engine's
         shapes: what the device runs (e.g. whether the Pallas kernel is in
         it as a ``tpu_custom_call``)."""
-        if self.offload_mode != "zero_copy":
-            raise NotImplementedError("decode_step_hlo needs zero_copy")
-        toks = jnp.zeros((self.n_slots, 1), jnp.int32)
-        kv_len = jnp.zeros((self.n_slots,), jnp.int32)
-        if self.sched is not None:
-            lowered = self._decode_m.lower(
-                self.params, toks, kv_len, self._tables_dev,
-                jnp.zeros((self.n_slots,), bool), self.cache)
-        else:
-            lowered = self._decode.lower(self.params, toks, kv_len,
-                                         self._tables_dev, self.cache)
+        if self.sched is None or self.offload_mode != "zero_copy":
+            raise NotImplementedError(
+                "decode_step_hlo needs the continuous zero-copy engine")
+        lowered = self._decode_m.lower(
+            self.params, jnp.zeros((self.n_slots, 1), jnp.int32),
+            jnp.zeros((self.n_slots,), jnp.int32), self._tables_dev,
+            jnp.zeros((self.n_slots,), bool), self.cache)
         return lowered.compile().as_text()
 
     def stats(self) -> dict:

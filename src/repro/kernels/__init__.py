@@ -1,9 +1,9 @@
 """Pallas TPU kernels for the compute hot-spots the paper optimizes.
 
 Every kernel takes ``interpret=None`` by default and resolves it through
-:func:`interpret_mode`: compiled on a TPU, interpreted everywhere else. No
-kernel decides this on its own, so nothing on the TPU path can silently run
-the interpreter.
+:func:`resolve_interpret`: compiled on a TPU, interpreted everywhere else.
+No kernel decides this on its own, so nothing on the TPU path can silently
+run the interpreter.
 """
 from __future__ import annotations
 
@@ -12,13 +12,9 @@ from typing import Optional
 import jax
 
 
-def interpret_mode(platform: Optional[str] = None) -> bool:
-    """Whether Pallas kernels run in interpret mode on ``platform``
-    (default: JAX's default backend). False only on a TPU."""
-    return (platform or jax.default_backend()) != "tpu"
-
-
-def resolve_interpret(interpret: Optional[bool]) -> bool:
-    """A kernel's ``interpret`` argument: an explicit bool wins, None
-    follows the platform."""
-    return interpret_mode() if interpret is None else bool(interpret)
+def resolve_interpret(interpret: Optional[bool] = None) -> bool:
+    """A kernel's ``interpret`` argument: an explicit bool wins; None means
+    interpret mode everywhere but on a TPU (JAX's default backend)."""
+    if interpret is not None:
+        return bool(interpret)
+    return jax.default_backend() != "tpu"

@@ -59,58 +59,80 @@ def _kernel(table_ref, len_ref,        # scalar-prefetch (SMEM)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0].astype(jnp.float32)           # (Hq, D)
-    Hq, D = q.shape
-    Hkv = k_ref.shape[-2]
-    G = Hq // Hkv
+    _, Hkv, G, D = q_ref.shape                 # q block: (1, Hkv, G, D)
     # KV block: (1, 1, page, Hkv, D) per-slot, (1, page, Hkv, D) global —
     # the same page once the leading singleton block dims are indexed away.
     lead = (0,) * (k_ref.ndim - 3)
-    # q head h attends KV head h // G. Every contraction is a 2-D dot per
-    # KV head: all Hq query rows against that head's (page, D) keys, keeping
-    # the rows of its group. The extra rows cost MXU work only; a batched
-    # dot over heads does not lower on the TPU.
-    group = jax.lax.broadcasted_iota(jnp.int32, (Hq, 1), 0) // G
-    hi = jax.lax.Precision.HIGHEST
-    s = jnp.zeros((Hq, page), jnp.float32)
-    for h in range(Hkv):
-        kh = k_ref[lead + (slice(None), h, slice(None))].astype(jnp.float32)
-        sh = jax.lax.dot_general(q, kh, (((1,), (1,)), ((), ())),
-                                 precision=hi,
-                                 preferred_element_type=jnp.float32)
-        s = jnp.where(group == h, sh, s)       # (Hq, page)
-    s = s * (D ** -0.5)
-    if softcap is not None:
-        s = softcap * jnp.tanh(s / softcap)
-
+    dt = jnp.promote_types(q_ref.dtype, k_ref.dtype)
     length = len_ref[b]
     pos = p * page + jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)
     valid = pos < length
-    # mask before the max: stale KV in a recycled page must not move m
-    s = jnp.where(valid, s, -jnp.inf)
 
-    m_prev, l_prev = m_ref[...], l_ref[...]    # (Hq, 1)
-    m_cur = jnp.max(s, axis=-1, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_cur)
-    m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-    p_ = jnp.exp(s - m_safe)                   # (Hq, page)
-    p_ = jnp.where(valid, p_, 0.0)
-    corr = jnp.where(jnp.isfinite(m_prev),
-                     jnp.exp(m_prev - m_safe), 0.0)
-    l_new = l_prev * corr + jnp.sum(p_, axis=-1, keepdims=True)
-    pv = jnp.zeros((Hq, D), jnp.float32)
+    # KV head h serves the G query rows q_ref[0, h]: every contraction is one
+    # 2-D dot per KV head (a batched dot over heads does not lower on the
+    # TPU). q.k of bf16 operands is exact in float32 accumulation; the
+    # probabilities are float32, so PV runs at HIGHEST.
     for h in range(Hkv):
-        vh = v_ref[lead + (slice(None), h, slice(None))].astype(jnp.float32)
-        pvh = jnp.dot(p_, vh, precision=hi,
-                      preferred_element_type=jnp.float32)
-        pv = jnp.where(group == h, pvh, pv)   # (Hq, D)
-    acc_ref[...] = acc_ref[...] * corr + pv
-    m_ref[...], l_ref[...] = m_new, l_new
+        kv = lead + (slice(None), h, slice(None))
+        s = jax.lax.dot_general(q_ref[0, h].astype(dt), k_ref[kv].astype(dt),
+                                (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        s = s * (D ** -0.5)                    # (G, page)
+        if softcap is not None:
+            s = softcap * jnp.tanh(s / softcap)
+        # mask before the max: stale KV in a recycled page must not move m
+        s = jnp.where(valid, s, -jnp.inf)
+
+        m_prev, l_prev = m_ref[h], l_ref[h]    # (G, 1)
+        m_cur = jnp.max(s, axis=-1, keepdims=True)
+        m_new = jnp.maximum(m_prev, m_cur)
+        m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
+        p_ = jnp.exp(s - m_safe)
+        p_ = jnp.where(valid, p_, 0.0)
+        corr = jnp.where(jnp.isfinite(m_prev),
+                         jnp.exp(m_prev - m_safe), 0.0)
+        l_ref[h] = l_prev * corr + jnp.sum(p_, axis=-1, keepdims=True)
+        pv = jnp.dot(p_, v_ref[kv].astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST,
+                     preferred_element_type=jnp.float32)
+        acc_ref[h] = acc_ref[h] * corr + pv   # (G, D)
+        m_ref[h] = m_new
 
     @pl.when(p == n_pages - 1)
     def _finish():
         l = jnp.maximum(l_ref[...], 1e-20)
         o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+
+
+def _decode_call(q, k_pool, v_pool, block_table, lengths, kv_spec, *,
+                 n_pages: int, softcap, interpret):
+    """One pallas_call over the grid (B, n_pages); ``kv_spec`` maps a grid
+    step to its KV page through the SMEM-resident table. q is viewed as
+    (B, Hkv, G, D): q head h attends KV head h // G."""
+    B, Hq, D = q.shape
+    page, Hkv = k_pool.shape[-3], k_pool.shape[-2]
+    G = Hq // Hkv
+    q_spec = pl.BlockSpec((1, Hkv, G, D), lambda b, p, tbl, ln: (b, 0, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B, n_pages),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
+        scratch_shapes=[
+            pltpu.VMEM((Hkv, G, 1), jnp.float32),
+            pltpu.VMEM((Hkv, G, 1), jnp.float32),
+            pltpu.VMEM((Hkv, G, D), jnp.float32),
+        ],
+    )
+    kernel = functools.partial(_kernel, page=page, n_pages=n_pages,
+                               softcap=softcap)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
+        interpret=resolve_interpret(interpret),
+    )(block_table, lengths, q.reshape(B, Hkv, G, D), k_pool, v_pool)
+    return out.reshape(B, Hq, D)
 
 
 def paged_attention(q, k_pool, v_pool, block_table, lengths, *,
@@ -131,34 +153,13 @@ def paged_attention(q, k_pool, v_pool, block_table, lengths, *,
         block_table = jnp.broadcast_to(
             jnp.arange(n_pages, dtype=jnp.int32), block_table.shape)
 
-    grid = (B, n_pages)
-    kernel = functools.partial(_kernel, page=page, n_pages=n_pages,
-                               softcap=softcap)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, Hq, D), lambda b, p, tbl, ln: (b, 0, 0)),
-            # THE TECHNIQUE: the KV page DMA source address goes through the
-            # SMEM-resident block table (IOVA -> PA translation at zero cost)
-            pl.BlockSpec((1, 1, page, Hkv, D),
-                         lambda b, p, tbl, ln: (b, tbl[b, p], 0, 0, 0)),
-            pl.BlockSpec((1, 1, page, Hkv, D),
-                         lambda b, p, tbl, ln: (b, tbl[b, p], 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, Hq, D), lambda b, p, tbl, ln: (b, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((Hq, 1), jnp.float32),
-            pltpu.VMEM((Hq, 1), jnp.float32),
-            pltpu.VMEM((Hq, D), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hq, D), q.dtype),
-        interpret=resolve_interpret(interpret),
-    )(block_table, lengths, q, k_pool, v_pool)
+    # THE TECHNIQUE: the KV page DMA source address goes through the
+    # SMEM-resident block table (IOVA -> PA translation at zero cost)
+    kv_spec = pl.BlockSpec((1, 1, page, Hkv, D),
+                           lambda b, p, tbl, ln: (b, tbl[b, p], 0, 0, 0))
+    return _decode_call(q, k_pool, v_pool, block_table, lengths, kv_spec,
+                        n_pages=n_pages, softcap=softcap,
+                        interpret=interpret)
 
 
 def paged_attention_global(q, k_pool, v_pool, block_table, lengths, *,
@@ -184,9 +185,6 @@ def paged_attention_global(q, k_pool, v_pool, block_table, lengths, *,
         return paged_attention(q, kg, vg, ident, lengths, softcap=softcap,
                                interpret=interpret)
 
-    grid = (B, P)
-    kernel = functools.partial(_kernel, page=page, n_pages=P, softcap=softcap)
-
     def kv_index(b, p, tbl, ln):
         # THE TECHNIQUE, shared-pool form: the DMA source page is the
         # SMEM-resident translation. NULL entries are clamped to page 0 for
@@ -195,24 +193,6 @@ def paged_attention_global(q, k_pool, v_pool, block_table, lengths, *,
         t = tbl[b, p]
         return (jnp.where(t >= total, 0, t), 0, 0, 0)
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, Hq, D), lambda b, p, tbl, ln: (b, 0, 0)),
-            pl.BlockSpec((1, page, Hkv, D), kv_index),
-            pl.BlockSpec((1, page, Hkv, D), kv_index),
-        ],
-        out_specs=pl.BlockSpec((1, Hq, D), lambda b, p, tbl, ln: (b, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((Hq, 1), jnp.float32),
-            pltpu.VMEM((Hq, 1), jnp.float32),
-            pltpu.VMEM((Hq, D), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hq, D), q.dtype),
-        interpret=resolve_interpret(interpret),
-    )(block_table, lengths, q, k_pool, v_pool)
+    return _decode_call(q, k_pool, v_pool, block_table, lengths,
+                        pl.BlockSpec((1, page, Hkv, D), kv_index),
+                        n_pages=P, softcap=softcap, interpret=interpret)

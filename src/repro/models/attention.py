@@ -360,7 +360,7 @@ def paged_decode_attention(q: jax.Array, kv: PagedKV, *,
     kernel (kernels/paged_attention): the block table lives in SMEM and
     drives the KV page DMAs directly — the paper's PTW-in-LLC realized on
     the serving hot path (compiled on a TPU, interpret mode elsewhere; see
-    ``repro.kernels.interpret_mode``). Both PagedKV layouts are supported;
+    ``repro.kernels.resolve_interpret``). Both PagedKV layouts are supported;
     a table with a leading stacked-blocks axis (outside the layer scan) is
     refused rather than served by the pure-JAX path.
 

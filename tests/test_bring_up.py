@@ -10,7 +10,7 @@ import jax
 import pytest
 
 import chip_smoke
-from repro.kernels import interpret_mode, resolve_interpret
+from repro.kernels import resolve_interpret
 from repro.launch import compile_cache
 from repro.launch.serve import build_config, build_engine
 from repro.models import init_params
@@ -104,8 +104,9 @@ def test_pallas_backend_refuses_stacked_table():
 
 @pytest.mark.parametrize("platform,interpret", [
     ("tpu", False), ("cpu", True), ("gpu", True)])
-def test_interpret_mode_by_platform(platform, interpret):
-    assert interpret_mode(platform) is interpret
+def test_interpret_mode_by_platform(monkeypatch, platform, interpret):
+    monkeypatch.setattr(jax, "default_backend", lambda: platform)
+    assert resolve_interpret() is interpret
 
 
 def test_resolve_interpret_follows_platform():

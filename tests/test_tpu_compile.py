@@ -5,6 +5,8 @@ A compile that passes here is not a chip run; it proves the kernel lowers
 (no interpret mode) and that the compiled program holds it. The topology is
 described only inside a fixture, so importing this file never loads the TPU
 library."""
+import os
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -21,6 +23,8 @@ POOL_PAGES = 4096                # a 64k-token global pool
 @pytest.fixture(scope="module")
 def one_chip():
     from jax.experimental import topologies
+    # the TPU compiler otherwise writes its logs under /tmp
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
     try:
         topo = topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
