@@ -1,0 +1,6 @@
+"""95th percentile of all gaps between consecutive output tokens."""
+from bench.metrics import gaps_ms, percentile
+
+
+def read(ctx):
+    return percentile(gaps_ms(ctx.window), 95)

@@ -1,0 +1,11 @@
+"""Device time of one call of the chunked-prefill program, averaged over
+its calls in the traced window."""
+
+
+def read(ctx):
+    if ctx.trace is None:
+        return None
+    ev = ctx.trace.program("_prefill_zero_copy")
+    if not ev:
+        return None
+    return sum(e.dur for e in ev) / len(ev) / 1e6

@@ -1,0 +1,65 @@
+"""FLOPs and bytes from shapes, against hand counts: the committed
+qwen2-7b configuration (GQA with QKV bias) and DeepSeek-LLM-7B's published
+widths cut to 10 layers (full multi-head attention, no bias)."""
+import pytest
+
+from bench import work
+from bench.model import load_config
+
+
+DEEPSEEK_7B = {"hidden_size": 4096, "num_attention_heads": 32,
+               "num_key_value_heads": 32, "num_hidden_layers": 10,
+               "intermediate_size": 11008, "vocab_size": 102400,
+               "architecture": {"qkv_bias": False}}
+
+
+def dims(name):
+    if name == "deepseek-llm-7b":
+        return work.Dims(layers=10, d_model=4096, n_heads=32, n_kv_heads=32,
+                         d_head=128, d_ff=11008, vocab=102400, qkv_bias=False)
+    return work.Dims.from_config(load_config(name))
+
+
+def test_from_config_reads_the_published_keys():
+    assert work.Dims.from_config(DEEPSEEK_7B) == dims("deepseek-llm-7b")
+
+
+def test_qwen2_7b_counts():
+    d = dims("qwen2-7b")
+    # q 3584x28x128, k/v 3584x4x128, o 28x128x3584, MLP 3 x 3584x18944
+    attn = 3584 * 3584 + 2 * 3584 * 512 + 3584 * 3584
+    assert d.layer_matmul_params == attn + 3 * 3584 * 18944 == 233_046_016
+    assert d.layer_params == 233_046_016 + (28 + 8) * 128 + 2 * 3584
+    assert d.kv_bytes_per_token == 2 * 14 * 4 * 128 * 2 == 28_672
+    assert d.weight_bytes() == 2 * (14 * d.layer_params
+                                    + 2 * 152064 * 3584 + 3584)
+
+
+def test_deepseek_counts():
+    d = dims("deepseek-llm-7b")
+    assert d.layer_matmul_params == 4 * 4096 * 4096 + 3 * 4096 * 11008
+    assert d.layer_params == d.layer_matmul_params + 2 * 4096   # no bias
+    assert d.kv_bytes_per_token == 2 * 10 * 32 * 128 * 2 == 163_840
+
+
+def test_decode_step_by_hand():
+    d = dims("deepseek-llm-7b")
+    ctx = [100, 2000]
+    f, b = work.decode_step(d, ctx)
+    per_tok = 2 * 10 * d.layer_matmul_params + 2 * 4096 * 102400
+    attn = 4 * 10 * 32 * 128 * (100 + 2000)
+    assert f == 2 * per_tok + attn
+    weights = 2 * (10 * d.layer_params + 4096 * 102400 + 4096 + 2 * 4096)
+    kv = 163_840 * (99 + 1999) + 163_840 * 2
+    assert b == weights + kv
+
+
+def test_prefill_chunk_by_hand():
+    d = dims("qwen2-7b")
+    # positions 64..127 attend over 65..128 keys: sum = 6240
+    f = work.prefill_chunk_flops(d, 64, 128, final=False)
+    assert f == 64 * 2 * 14 * d.layer_matmul_params \
+        + 4 * 14 * 28 * 128 * sum(range(65, 129))
+    g = work.prefill_chunk_flops(d, 64, 128, final=True)
+    assert g - f == 2 * 3584 * 152064
+    assert work.step_flops(d, [], [(64, 128, True)]) == g
