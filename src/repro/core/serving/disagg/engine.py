@@ -30,6 +30,8 @@ from __future__ import annotations
 import time
 from typing import Dict, Optional
 
+from jax.profiler import TraceAnnotation
+
 from repro.configs.base import ModelConfig
 from repro.core.serving.disagg.workers import (DecodeWorker, KVTransferEngine,
                                                PrefillScheduler, PrefillWorker)
@@ -83,23 +85,9 @@ class DisaggEngine(ServingEngine):
     # ------------------------------------------------------------ step
     def _continuous_step(self):
         # Pending device page copies (CoW divergences AND copy-mode
-        # transfer payloads) must land before anything can recycle their
-        # source pages — same invariant as the colocated step.
-        self._apply_cow()
-        while self.queue:
-            req = self.queue.popleft()
-            self.sched.submit(req.req_id, req.prompt, req.max_tokens,
-                              tenant=req.tenant)
-            self._waiting_reqs[req.req_id] = req
-        t0 = time.perf_counter()
-        out = self.sched.schedule()
-        self.metrics["admit_s"] += time.perf_counter() - t0
-        for sid, folded in out.preempted:
-            req = self.active.pop(sid)
-            req.out_tokens.extend(folded)
-            self._waiting_reqs[sid] = req
-        for sid in out.admitted + out.resumed:
-            self.active[sid] = self._waiting_reqs.pop(sid)
+        # transfer payloads) land first, inside _schedule, before anything
+        # can recycle their source pages — as in the colocated step.
+        out = self._schedule()
         if out.chunks:
             self._chunk_prefill(out.chunks)
         # Prefill-complete sequences queue for migration; the pump moves
@@ -211,29 +199,31 @@ class DisaggEngine(ServingEngine):
         return resident
 
     def _release_done(self, finished: Dict[int, Request]) -> None:
-        for rid in [r for r, q in self.active.items()
-                    if self.mgr.seqs[r].done]:
-            req = self.active.pop(rid)
-            req.done_at = time.perf_counter()
-            st = self.mgr.seqs[rid]
-            slot = st.slot
-            req.out_tokens.extend(st.tokens)
-            if rid in self.decode_worker.running:
-                self.decode_worker.finish(rid)
-            else:
-                # Completed at prefill (max_tokens == 1 / EOS first token):
-                # never migrated, still the prefill scheduler's.
-                self.sched.finish(rid)
-            if self.translation_trace is not None:
-                self.translation_trace.append(
-                    ("unmap", slot, len(st.pages)))
-            self.mgr.release(rid)
-            finished[rid] = req
-            if slot in self.decode_worker.slots:
-                # release() returned the slot to general admission; pull it
-                # back out — decode slots are only ever migration targets.
-                self.mgr.reserve_slots([slot])
-                self.xfer_engine.free_decode.append(slot)
+        done = [r for r in self.active if self.mgr.seqs[r].done]
+        with TraceAnnotation("engine.release", finished=len(done)):
+            for rid in done:
+                req = self.active.pop(rid)
+                req.done_at = time.perf_counter()
+                st = self.mgr.seqs[rid]
+                slot = st.slot
+                req.out_tokens.extend(st.tokens)
+                if rid in self.decode_worker.running:
+                    self.decode_worker.finish(rid)
+                else:
+                    # Completed at prefill (max_tokens == 1 / EOS first
+                    # token): never migrated, still the prefill scheduler's.
+                    self.sched.finish(rid)
+                if self.translation_trace is not None:
+                    self.translation_trace.append(
+                        ("unmap", slot, len(st.pages)))
+                self.mgr.release(rid)
+                finished[rid] = req
+                if slot in self.decode_worker.slots:
+                    # release() returned the slot to general admission; pull
+                    # it back out — decode slots are only ever migration
+                    # targets.
+                    self.mgr.reserve_slots([slot])
+                    self.xfer_engine.free_decode.append(slot)
 
     def stats(self) -> dict:
         s = super().stats()

@@ -61,6 +61,7 @@ from typing import Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig
 from repro.core.serving.scheduler import Scheduler, SchedulerOutput
@@ -72,6 +73,16 @@ from repro.models import (MeshInfo, NO_MESH, forward_decode, forward_prefill,
                           init_cache)
 from repro.models import attention as attn
 from repro.models.blocks import MAMBA_KINDS, _sp_mode
+
+# Host spans of one continuous step, written into a running jax.profiler
+# trace on the clock of its device events; with no trace running a span
+# costs about a microsecond. Every span the step opens is named here, in
+# nesting order (engine.step holds the rest).
+SPANS = ("engine.step", "engine.cow", "engine.schedule",
+         "engine.prefill.pack", "engine.prefill.launch",
+         "engine.prefill.sync", "engine.prefill.commit", "engine.tables",
+         "engine.decode.pack", "engine.decode.launch", "engine.decode.sync",
+         "engine.decode.commit", "engine.release")
 
 
 @dataclass
@@ -339,9 +350,12 @@ class ServingEngine:
         # lengths (batching only same-length prompts).
         self._exact_prefill = any(k in MAMBA_KINDS or k == "rwkv"
                                   for k in cfg.layer_kinds())
-        self.metrics = {"prefills": 0, "prefill_reqs": 0, "decode_steps": 0,
+        self.metrics = {"prefills": 0, "decode_steps": 0,
                         "tokens": 0, "staging_copies": 0, "prefill_s": 0.0,
-                        "decode_s": 0.0, "admit_s": 0.0,
+                        "admit_s": 0.0,
+                        # prompt tokens prefilled, and the padded slots the
+                        # prefill program ran over (rows x bucketed length)
+                        "prefill_tokens": 0, "prefill_padded_tokens": 0,
                         "table_uploads_full": 0, "table_uploads_delta": 0,
                         "table_rows_uploaded": 0, "table_upload_bytes": 0,
                         "admit_table_bytes": 0,
@@ -439,17 +453,18 @@ class ServingEngine:
         """Run ONE engine step (admission + compute + completion harvest)
         under the configured scheduler. Benchmarks drive this directly to
         inject arrivals between steps; :meth:`run` is the closed loop."""
-        if self.sched is not None:
-            self._continuous_step()
-        else:
-            self._admit()
-            # Prefill's first token can already finish a request
-            # (max_tokens=1): release it before the decode step, which
-            # appends a token to every active request.
+        with TraceAnnotation("engine.step", step=self._step_count):
+            if self.sched is not None:
+                self._continuous_step()
+            else:
+                self._admit()
+                # Prefill's first token can already finish a request
+                # (max_tokens=1): release it before the decode step, which
+                # appends a token to every active request.
+                self._release_done(finished)
+                self._decode_step()
+            self._step_count += 1
             self._release_done(finished)
-            self._decode_step()
-        self._step_count += 1
-        self._release_done(finished)
 
     def run(self, max_steps: int = 10_000) -> Dict[int, Request]:
         finished: Dict[int, Request] = {}
@@ -460,23 +475,24 @@ class ServingEngine:
         return finished
 
     def _release_done(self, finished: Dict[int, Request]) -> None:
-        for rid in [r for r, q in self.active.items()
-                    if self.mgr.seqs[r].done]:
-            req = self.active.pop(rid)
-            req.done_at = time.perf_counter()
-            st = self.mgr.seqs[rid]
-            if self.sched is not None:
-                # Generations preempted along the way were folded into
-                # out_tokens at preemption time; append the rest.
-                req.out_tokens.extend(st.tokens)
-                self.sched.finish(rid)
-            else:
-                req.out_tokens = st.tokens
-            if self.translation_trace is not None:
-                self.translation_trace.append(
-                    ("unmap", st.slot, len(st.pages)))
-            self.mgr.release(rid)
-            finished[rid] = req
+        done = [r for r in self.active if self.mgr.seqs[r].done]
+        with TraceAnnotation("engine.release", finished=len(done)):
+            for rid in done:
+                req = self.active.pop(rid)
+                req.done_at = time.perf_counter()
+                st = self.mgr.seqs[rid]
+                if self.sched is not None:
+                    # Generations preempted along the way were folded into
+                    # out_tokens at preemption time; append the rest.
+                    req.out_tokens.extend(st.tokens)
+                    self.sched.finish(rid)
+                else:
+                    req.out_tokens = st.tokens
+                if self.translation_trace is not None:
+                    self.translation_trace.append(
+                        ("unmap", st.slot, len(st.pages)))
+                self.mgr.release(rid)
+                finished[rid] = req
 
     def _trace_event(self, ev: tuple) -> None:
         """Scheduler lifecycle events (map/unmap/preempt/resume) join the
@@ -615,7 +631,8 @@ class ServingEngine:
             req.first_token_at = now
             req.first_token_step = self._step_count
         self.metrics["prefills"] += 1
-        self.metrics["prefill_reqs"] += len(group)
+        self.metrics["prefill_tokens"] += sum(sufs)
+        self.metrics["prefill_padded_tokens"] += nb * lb
         self.metrics["prefill_s"] += time.perf_counter() - t0
 
     def _prefill_zero_copy(self, params, batch, cache):
@@ -664,7 +681,8 @@ class ServingEngine:
         req.first_token_at = time.perf_counter()
         req.first_token_step = self._step_count
         self.metrics["prefills"] += 1
-        self.metrics["prefill_reqs"] += 1
+        self.metrics["prefill_tokens"] += len(req.prompt)
+        self.metrics["prefill_padded_tokens"] += len(req.prompt)
         self.metrics["prefill_s"] += time.perf_counter() - t0
 
     # --------------------------------------------------------------- CoW
@@ -699,41 +717,48 @@ class ServingEngine:
         pairs = self.mgr.drain_cow_copies()
         if not pairs:
             return
-        if self.translation_trace is not None:
-            # A CoW remap is a fresh mapping: the host map pass warms the
-            # duplicated pages' PTE lines before the device touches them.
-            self.translation_trace.append(("map", [d for _, d in pairs]))
-        n = 1
-        while n < len(pairs):
-            n *= 2
-        src = np.zeros((n,), np.int32)
-        dst = np.full((n,), self.mgr.null_page, np.int32)  # pad: dropped
-        for i, (s, d) in enumerate(pairs):
-            src[i], dst[i] = s, d
-        self.cache = self._cow(self.cache, jnp.asarray(src), jnp.asarray(dst))
-        self.metrics["cow_page_copies"] += len(pairs)
+        with TraceAnnotation("engine.cow", pairs=len(pairs)):
+            if self.translation_trace is not None:
+                # A CoW remap is a fresh mapping: the host map pass warms
+                # the duplicated pages' PTE lines before the device touches
+                # them.
+                self.translation_trace.append(("map", [d for _, d in pairs]))
+            n = 1
+            while n < len(pairs):
+                n *= 2
+            src = np.zeros((n,), np.int32)
+            dst = np.full((n,), self.mgr.null_page, np.int32)  # pad: dropped
+            for i, (s, d) in enumerate(pairs):
+                src[i], dst[i] = s, d
+            self.cache = self._cow(self.cache, jnp.asarray(src),
+                                   jnp.asarray(dst))
+            self.metrics["cow_page_copies"] += len(pairs)
 
     # --------------------------------------------------------------- decode
     def _upload_tables(self):
         """Delta table upload: send only rows that changed since last step;
         a full-table upload happens only after an epoch invalidation."""
-        if self.mgr.epoch != self._epoch_seen:
-            self.mgr.delta_rows()                    # superseded by the full upload
-            self._tables_dev = jnp.asarray(self.mgr.tables)
-            self._epoch_seen = self.mgr.epoch
-            self.metrics["table_uploads_full"] += 1
-            self.metrics["table_rows_uploaded"] += self.n_slots
-            self.metrics["table_upload_bytes"] += int(self.mgr.tables.nbytes)
-            return
-        rows = self.mgr.delta_rows()
-        if rows:
-            idx = np.asarray(rows)
-            sub = self.mgr.tables[idx]
-            self._tables_dev = self._tables_dev.at[jnp.asarray(idx)].set(
-                jnp.asarray(sub))
-            self.metrics["table_uploads_delta"] += 1
-            self.metrics["table_rows_uploaded"] += len(rows)
-            self.metrics["table_upload_bytes"] += int(sub.nbytes)
+        with TraceAnnotation("engine.tables") as span:
+            if self.mgr.epoch != self._epoch_seen:
+                self.mgr.delta_rows()    # superseded by the full upload
+                self._tables_dev = jnp.asarray(self.mgr.tables)
+                self._epoch_seen = self.mgr.epoch
+                self.metrics["table_uploads_full"] += 1
+                self.metrics["table_rows_uploaded"] += self.n_slots
+                self.metrics["table_upload_bytes"] += \
+                    int(self.mgr.tables.nbytes)
+                span.set_metadata(rows=self.n_slots)
+                return
+            rows = self.mgr.delta_rows()
+            span.set_metadata(rows=len(rows))
+            if rows:
+                idx = np.asarray(rows)
+                sub = self.mgr.tables[idx]
+                self._tables_dev = self._tables_dev.at[jnp.asarray(idx)].set(
+                    jnp.asarray(sub))
+                self.metrics["table_uploads_delta"] += 1
+                self.metrics["table_rows_uploaded"] += len(rows)
+                self.metrics["table_upload_bytes"] += int(sub.nbytes)
 
     def _decode_zero_copy(self, params, tokens, kv_len, tables, cache):
         cache = _install_tables(cache, tables, kv_len)
@@ -742,7 +767,6 @@ class ServingEngine:
     def _decode_step(self):
         if not self.active:
             return
-        t0 = time.perf_counter()
         lengths = self.mgr.device_lengths()
         # KV length = tokens whose KV is in cache; exactly one token is
         # pending per active sequence (the one this step feeds in).
@@ -787,31 +811,41 @@ class ServingEngine:
                 st.done = True
         self.metrics["decode_steps"] += 1
         self.mgr.observe_step()
-        self.metrics["decode_s"] += time.perf_counter() - t0
 
     # ------------------------------------------------- continuous batching
     def _continuous_step(self):
+        out = self._schedule()
+        if out.chunks:
+            self._chunk_prefill(out.chunks)
+        self._decode_continuous(out)
+
+    def _schedule(self) -> SchedulerOutput:
+        """Land queued CoW copies, hand the queue to the scheduler, compose
+        this step, and move preempted and admitted requests between
+        ``active`` and ``_waiting_reqs``."""
         # Queued CoW copies must land BEFORE the scheduler can preempt: a
         # preemption frees its sequence's pages, and a same-step resume or
         # chunk prefill could recycle a pending copy's source page.
         self._apply_cow()
-        while self.queue:
-            req = self.queue.popleft()
-            self.sched.submit(req.req_id, req.prompt, req.max_tokens,
-                              tenant=req.tenant)
-            self._waiting_reqs[req.req_id] = req
-        t0 = time.perf_counter()
-        out = self.sched.schedule()
-        self.metrics["admit_s"] += time.perf_counter() - t0
-        for sid, folded in out.preempted:
-            req = self.active.pop(sid)
-            req.out_tokens.extend(folded)
-            self._waiting_reqs[sid] = req
-        for sid in out.admitted + out.resumed:
-            self.active[sid] = self._waiting_reqs.pop(sid)
-        if out.chunks:
-            self._chunk_prefill(out.chunks)
-        self._decode_continuous(out)
+        with TraceAnnotation("engine.schedule") as span:
+            while self.queue:
+                req = self.queue.popleft()
+                self.sched.submit(req.req_id, req.prompt, req.max_tokens,
+                                  tenant=req.tenant)
+                self._waiting_reqs[req.req_id] = req
+            t0 = time.perf_counter()
+            out = self.sched.schedule()
+            self.metrics["admit_s"] += time.perf_counter() - t0
+            for sid, folded in out.preempted:
+                req = self.active.pop(sid)
+                req.out_tokens.extend(folded)
+                self._waiting_reqs[sid] = req
+            for sid in out.admitted + out.resumed:
+                self.active[sid] = self._waiting_reqs.pop(sid)
+            span.set_metadata(decodes=len(out.decode_slots),
+                              chunks=len(out.chunks),
+                              preempted=len(out.preempted))
+        return out
 
     def _chunk_prefill(self, chunks):
         """One padded prefill call for this step's chunk spans — the
@@ -824,60 +858,72 @@ class ServingEngine:
         (still unwritten). The final chunk's logits produce the first
         token (or re-inject a preempted sequence's pending token)."""
         t0 = time.perf_counter()
-        sufs = [c.end - c.start for c in chunks]
-        lb = self._bucket_len(max(sufs))
-        nb = 1
-        while nb < len(chunks):
-            nb *= 2
-        nb = max(min(nb, self.n_slots), len(chunks))
-        tokens = np.zeros((nb, lb), np.int32)
-        lengths = np.zeros((nb,), np.int32)
-        prefix = np.zeros((nb,), np.int32)
-        slots = np.full((nb,), self.n_slots, np.int32)  # OOB: scatter-dropped
-        tables = np.full((nb, self.max_pages), self.mgr.null_page, np.int32)
-        wtables = np.full((nb, self.max_pages), self.mgr.null_page, np.int32)
-        for i, c in enumerate(chunks):
-            st = self.mgr.seqs[c.seq_id]
-            tokens[i, :sufs[i]] = self.buffer.chunk_tokens(c.slot, c.start,
-                                                           c.end)
-            lengths[i] = sufs[i]
-            prefix[i] = c.start
-            slots[i] = c.slot
-            tables[i] = self.mgr.tables[c.slot]
-            wtables[i] = tables[i]
-            keep_from = max(st.shared_pages, c.start // self.page_size)
-            wtables[i, :keep_from] = self.mgr.null_page
-        self.metrics["admit_table_bytes"] += len(chunks) * self.max_pages * 4
-        batch = {"tokens": jnp.asarray(tokens),
-                 "lengths": jnp.asarray(lengths),
-                 "tables": jnp.asarray(tables),
-                 "slots": jnp.asarray(slots),
-                 "prefix_lens": jnp.asarray(prefix),
-                 "write_tables": jnp.asarray(wtables)}
-        logits, self.cache = self._prefill(self.params, batch, self.cache)
+        with TraceAnnotation("engine.prefill.pack") as span:
+            sufs = [c.end - c.start for c in chunks]
+            lb = self._bucket_len(max(sufs))
+            nb = 1
+            while nb < len(chunks):
+                nb *= 2
+            nb = max(min(nb, self.n_slots), len(chunks))
+            real, padded = sum(sufs), nb * lb
+            span.set_metadata(rows=nb, tokens=padded, real=real)
+            tokens = np.zeros((nb, lb), np.int32)
+            lengths = np.zeros((nb,), np.int32)
+            prefix = np.zeros((nb,), np.int32)
+            slots = np.full((nb,), self.n_slots, np.int32)  # OOB: dropped
+            tables = np.full((nb, self.max_pages), self.mgr.null_page,
+                             np.int32)
+            wtables = np.full((nb, self.max_pages), self.mgr.null_page,
+                              np.int32)
+            for i, c in enumerate(chunks):
+                st = self.mgr.seqs[c.seq_id]
+                tokens[i, :sufs[i]] = self.buffer.chunk_tokens(
+                    c.slot, c.start, c.end)
+                lengths[i] = sufs[i]
+                prefix[i] = c.start
+                slots[i] = c.slot
+                tables[i] = self.mgr.tables[c.slot]
+                wtables[i] = tables[i]
+                keep_from = max(st.shared_pages, c.start // self.page_size)
+                wtables[i, :keep_from] = self.mgr.null_page
+            self.metrics["admit_table_bytes"] += \
+                len(chunks) * self.max_pages * 4
+            batch = {"tokens": jnp.asarray(tokens),
+                     "lengths": jnp.asarray(lengths),
+                     "tables": jnp.asarray(tables),
+                     "slots": jnp.asarray(slots),
+                     "prefix_lens": jnp.asarray(prefix),
+                     "write_tables": jnp.asarray(wtables)}
+        with TraceAnnotation("engine.prefill.launch"):
+            logits, self.cache = self._prefill(self.params, batch, self.cache)
         finals = [(i, c) for i, c in enumerate(chunks) if c.is_final]
         if finals:
-            logits = np.asarray(logits)
-        now = time.perf_counter()
-        for i, c in enumerate(chunks):
-            self.buffer.advance(c.slot, c.end)
-            # Progressive prefix registration: the chunk's KV is resident
-            # NOW, so its pages may join the index (an eager registration
-            # at lazy admission would publish uncomputed pages).
-            self.mgr.register_progress(c.seq_id,
-                                       self.buffer.token_ids[c.slot], c.end)
-        for i, c in finals:
-            first = (c.pending if c.pending is not None
-                     else int(np.argmax(logits[i, -1])))
-            self.mgr.append_token(c.seq_id, first)
-            self.buffer.append(c.slot, first)
-            req = self.active[c.seq_id]
-            if req.first_token_at is None:
-                req.first_token_at = now
-                req.first_token_step = self._step_count
-        self.metrics["prefills"] += 1
-        self.metrics["prefill_reqs"] += len(finals)
-        self.metrics["prefill_s"] += time.perf_counter() - t0
+            with TraceAnnotation("engine.prefill.sync"):
+                logits = np.asarray(logits)
+        with TraceAnnotation("engine.prefill.commit"):
+            now = time.perf_counter()
+            for i, c in enumerate(chunks):
+                self.buffer.advance(c.slot, c.end)
+                # Progressive prefix registration: the chunk's KV is
+                # resident NOW, so its pages may join the index (an eager
+                # registration at lazy admission would publish uncomputed
+                # pages).
+                self.mgr.register_progress(c.seq_id,
+                                           self.buffer.token_ids[c.slot],
+                                           c.end)
+            for i, c in finals:
+                first = (c.pending if c.pending is not None
+                         else int(np.argmax(logits[i, -1])))
+                self.mgr.append_token(c.seq_id, first)
+                self.buffer.append(c.slot, first)
+                req = self.active[c.seq_id]
+                if req.first_token_at is None:
+                    req.first_token_at = now
+                    req.first_token_step = self._step_count
+            self.metrics["prefills"] += 1
+            self.metrics["prefill_tokens"] += real
+            self.metrics["prefill_padded_tokens"] += padded
+            self.metrics["prefill_s"] += time.perf_counter() - t0
 
     def _decode_masked(self, params, tokens, kv_len, tables, mask, cache):
         """Decode with non-decoding slots masked out: their table rows
@@ -904,7 +950,6 @@ class ServingEngine:
         return resident
 
     def _decode_continuous(self, out: SchedulerOutput):
-        t0 = time.perf_counter()
         self._apply_cow()       # final-chunk first tokens may queue CoW
         self._upload_tables()
         if self._translation_stats:
@@ -916,31 +961,36 @@ class ServingEngine:
                         ("step", accesses, int(sum(resident.values()))))
         if not out.decode_slots:
             return
-        lengths = self.mgr.device_lengths()
-        mask = np.zeros((self.n_slots,), bool)
-        mask[out.decode_slots] = True
-        kv_len = np.where(mask, np.maximum(lengths - 1, 0), 0) \
-            .astype(np.int32)
-        last = np.where(mask, self.buffer.last_tokens(), 0) \
-            .astype(np.int32)[:, None]
-        logits, self.cache = self._decode_m(
-            self.params, jnp.asarray(last), jnp.asarray(kv_len),
-            self._tables_dev, jnp.asarray(mask), self.cache)
-        nxt = np.asarray(jnp.argmax(logits[:, 0], axis=-1))
-        for slot in out.decode_slots:
-            sid = int(self.buffer.seq_ids[slot])
-            tok = int(nxt[slot])
-            self.mgr.append_token(sid, tok)
-            self.buffer.append(slot, tok)
-            self.metrics["tokens"] += 1
-            req = self.active.get(sid)
-            if req is not None and req.first_decode_step is None:
-                req.first_decode_step = self._step_count
-            if self.eos is not None and tok == self.eos:
-                self.mgr.seqs[sid].done = True
-        self.metrics["decode_steps"] += 1
-        self.mgr.observe_step()
-        self.metrics["decode_s"] += time.perf_counter() - t0
+        rows = len(out.decode_slots)
+        with TraceAnnotation("engine.decode.pack", rows=rows):
+            lengths = self.mgr.device_lengths()
+            mask = np.zeros((self.n_slots,), bool)
+            mask[out.decode_slots] = True
+            kv_len = np.where(mask, np.maximum(lengths - 1, 0), 0) \
+                .astype(np.int32)
+            last = np.where(mask, self.buffer.last_tokens(), 0) \
+                .astype(np.int32)[:, None]
+            args = (jnp.asarray(last), jnp.asarray(kv_len), self._tables_dev,
+                    jnp.asarray(mask))
+        with TraceAnnotation("engine.decode.launch"):
+            logits, self.cache = self._decode_m(self.params, *args,
+                                                self.cache)
+        with TraceAnnotation("engine.decode.sync"):
+            nxt = np.asarray(jnp.argmax(logits[:, 0], axis=-1))
+        with TraceAnnotation("engine.decode.commit", rows=rows):
+            for slot in out.decode_slots:
+                sid = int(self.buffer.seq_ids[slot])
+                tok = int(nxt[slot])
+                self.mgr.append_token(sid, tok)
+                self.buffer.append(slot, tok)
+                self.metrics["tokens"] += 1
+                req = self.active.get(sid)
+                if req is not None and req.first_decode_step is None:
+                    req.first_decode_step = self._step_count
+                if self.eos is not None and tok == self.eos:
+                    self.mgr.seqs[sid].done = True
+            self.metrics["decode_steps"] += 1
+            self.mgr.observe_step()
 
     def decode_step_hlo(self) -> str:
         """Optimized HLO of the zero-copy decode step at this engine's

@@ -57,7 +57,8 @@ def _mlp_specs(cfg: ModelConfig):
 
 
 def _apply_mlp(p, x, cfg):
-    return mlp2(p, x, cfg.act) if "w1" in p else glu_mlp(p, x, cfg.act)
+    with jax.named_scope("mlp"):
+        return mlp2(p, x, cfg.act) if "w1" in p else glu_mlp(p, x, cfg.act)
 
 
 def layer_specs(cfg: ModelConfig, kind: str) -> Dict[str, Any]:
@@ -206,10 +207,12 @@ def _self_attention(p, x, ctx: FwdCtx, cache, window):
             o, kv_new = attn.sp_paged_decode(q, k, v, kv, mi.mesh,
                                              softcap=cfg.attn_softcap)
         else:
-            kv_new = attn.paged_append(kv, k, v)
-            o = attn.paged_decode_attention(q, kv_new,
-                                            softcap=cfg.attn_softcap,
-                                            backend=cfg.decode_backend)
+            with jax.named_scope("kv_write"):
+                kv_new = attn.paged_append(kv, k, v)
+            with jax.named_scope("attention"):
+                o = attn.paged_decode_attention(q, kv_new,
+                                                softcap=cfg.attn_softcap,
+                                                backend=cfg.decode_backend)
         return attn.out_proj(p, o), {**cache, "kv": kv_new}
     if (ctx.kv_prefix_lens is not None and ctx.mode == "prefill"
             and cache is not None and "kv" in cache
@@ -221,13 +224,16 @@ def _self_attention(p, x, ctx: FwdCtx, cache, window):
         q = rope(q, positions, cfg.rope_theta)
         k = rope(k, positions, cfg.rope_theta)
         kv: attn.PagedKV = cache["kv"]
-        o = attn.prefix_context_attention(
-            q, k, v, kv, ctx.kv_prefix_lens,
-            jnp.broadcast_to(ctx.seq_lengths, (B,)),
-            softcap=cfg.attn_softcap)
+        with jax.named_scope("attention"):
+            o = attn.prefix_context_attention(
+                q, k, v, kv, ctx.kv_prefix_lens,
+                jnp.broadcast_to(ctx.seq_lengths, (B,)),
+                softcap=cfg.attn_softcap)
         o = shard(o, mi, P("batch", None, "tp", None))
         y = attn.out_proj(p, o)
-        return y, {**cache, "kv": _prefill_write_global(kv, k, v, ctx, S)}
+        with jax.named_scope("kv_write"):
+            kv = _prefill_write_global(kv, k, v, ctx, S)
+        return y, {**cache, "kv": kv}
     positions = jnp.arange(S)[None] + ctx.q_offset
     q = rope(q, positions, cfg.rope_theta)
     k = rope(k, positions, cfg.rope_theta)
@@ -247,7 +253,9 @@ def _self_attention(p, x, ctx: FwdCtx, cache, window):
     if ctx.mode == "prefill" and cache is not None and "kv" in cache:
         kv: attn.PagedKV = cache["kv"]
         if attn.is_global_layout(kv):
-            return y, {**cache, "kv": _prefill_write_global(kv, k, v, ctx, S)}
+            with jax.named_scope("kv_write"):
+                kv = _prefill_write_global(kv, k, v, ctx, S)
+            return y, {**cache, "kv": kv}
         n_pages, page = kv.k_pool.shape[1], kv.k_pool.shape[2]
         eff = n_pages * page
 

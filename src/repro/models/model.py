@@ -290,8 +290,9 @@ def forward_prefill(cfg: ModelConfig, params, batch, cache,
     else:
         idx = jnp.maximum(lengths.astype(jnp.int32) - 1, 0)[:, None, None]
         x = jnp.take_along_axis(x, idx, axis=1)
-    x = rmsnorm(x, params["final_ln"], cfg.norm_eps)
-    logits = logits_fn(params["embed"], x, cfg.logit_softcap)
+    with jax.named_scope("lm_head"):
+        x = rmsnorm(x, params["final_ln"], cfg.norm_eps)
+        logits = logits_fn(params["embed"], x, cfg.logit_softcap)
     total = tokens.shape[1] if lengths is None else lengths
     if prefix_lens is not None:
         total = total + prefix_lens        # cache holds prefix + suffix
@@ -311,8 +312,9 @@ def forward_decode(cfg: ModelConfig, params, token, pos, cache,
     x, cache_out = _run_blocks(cfg, params, x, ctx, cache)
     if cfg.is_encdec:
         cache_out["enc_out"] = cache["enc_out"]
-    x = rmsnorm(x, params["final_ln"], cfg.norm_eps)
-    logits = logits_fn(params["embed"], x, cfg.logit_softcap)
+    with jax.named_scope("lm_head"):
+        x = rmsnorm(x, params["final_ln"], cfg.norm_eps)
+        logits = logits_fn(params["embed"], x, cfg.logit_softcap)
     return logits, cache_out
 
 
