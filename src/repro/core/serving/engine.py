@@ -145,6 +145,8 @@ def _write_slot(batch_cache, single_cache, slot: int):
     everything else has batch axis 0.
     """
     def walk(bt, st, under_blocks):
+        if bt is None:                     # PagedKV.layer outside a scan
+            return None
         if isinstance(bt, dict):
             return {k: walk(bt[k], st[k], under_blocks or k == "blocks")
                     for k in bt}

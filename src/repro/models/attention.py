@@ -277,11 +277,24 @@ class PagedKV(NamedTuple):
     length: () or (B,) int32 — tokens currently valid per sequence.
     The layouts are statically distinguishable by rank (see
     ``is_global_layout``), so one jitted step handles either.
+
+    layer: None, or an int32 scalar when this is one layer's view of a
+    STACKED global pool inside the layer loop (``models/model._run_blocks``):
+    k_pool / v_pool are then (n_blocks, total_pages, page, Hkv, D) — the
+    loop carry — while block_table / length are the layer's own. The
+    global-layout reads and writes index ``[layer, page, slot]`` (see
+    ``pool_index``), so no layer slice is taken and none is restacked.
     """
     k_pool: jax.Array
     v_pool: jax.Array
     block_table: jax.Array
     length: jax.Array
+    layer: Optional[jax.Array] = None
+
+    def pool_index(self, *idx):
+        """Index into k_pool / v_pool for this layer: ``idx`` behind the
+        stacked pool's layer index when the view has one."""
+        return idx if self.layer is None else (self.layer, *idx)
 
     @property
     def page_size(self) -> int:
@@ -301,9 +314,10 @@ def is_global_layout(kv: PagedKV) -> bool:
     Rank-based and therefore robust to a leading stacked-blocks axis:
     per-slot pools carry (pages, page, H, D) behind the table's (B, P) dims
     (+3 ranks), a global pool carries (total, page, H, D) beside a (B, P)
-    table (+2 ranks).
+    table (+2 ranks); a layer's view of the stacked global pool (``layer``
+    set) adds the blocks axis to the pool alone (+3 ranks).
     """
-    return kv.k_pool.ndim == kv.block_table.ndim + 2
+    return kv.k_pool.ndim == kv.block_table.ndim + 2 + (kv.layer is not None)
 
 
 def paged_kv_specs(cfg, batch: int, max_len: int, page_size: int,
@@ -330,11 +344,13 @@ def gather_global_pages(kv: PagedKV):
     """Logical (B, P, page, Hkv, D) view of a GLOBAL shared pool through the
     per-slot block table — the IOVA translation for the shared-pool layout.
     NULL entries (>= total pages: unallocated table slots) read as exact
-    zeros, matching a freshly zero-initialized per-slot pool bit-for-bit."""
-    total = kv.k_pool.shape[0]
+    zeros, matching a freshly zero-initialized per-slot pool bit-for-bit.
+    A layer view of the stacked pool is read through ``[layer, page]`` in
+    the same gather."""
+    total = kv.k_pool.shape[-4]
     tbl = kv.block_table
     null = (tbl >= total)[..., None, None, None]
-    safe = jnp.where(tbl >= total, 0, tbl)
+    safe = kv.pool_index(jnp.where(tbl >= total, 0, tbl))
     k = jnp.where(null, 0, kv.k_pool[safe]).astype(kv.k_pool.dtype)
     v = jnp.where(null, 0, kv.v_pool[safe]).astype(kv.v_pool.dtype)
     return k, v
@@ -381,6 +397,10 @@ def paged_decode_attention(q: jax.Array, kv: PagedKV, *,
     block table (B x n_pages ints) — instead of gathering the pool data
     itself. This removes a full pool copy per layer per step (the paper's
     map-don't-copy insight applied to the kernel's own data movement).
+    This holds for the per-slot layout. The global layout's whole-pool
+    copies were in the layer scan, which sliced each layer's pool out of
+    the stack and restacked it; that pool now rides the scan carry
+    (``PagedKV.layer``).
     """
     B, _, Hq, D = q.shape
     if backend == "pallas":
@@ -391,7 +411,10 @@ def paged_decode_attention(q: jax.Array, kv: PagedKV, *,
                 "leading stacked-blocks axis belongs inside the layer scan)")
         from repro.kernels.paged_attention.ops import paged_decode
         lengths = jnp.broadcast_to(kv.length, (B,)).astype(jnp.int32)
-        out = paged_decode(q[:, 0], kv.k_pool, kv.v_pool,
+        k_pool, v_pool = kv.k_pool, kv.v_pool
+        if kv.layer is not None:        # the kernel takes one layer's pool
+            k_pool, v_pool = k_pool[kv.layer], v_pool[kv.layer]
+        out = paged_decode(q[:, 0], k_pool, v_pool,
                            kv.block_table.astype(jnp.int32), lengths,
                            softcap=softcap)
         return out[:, None].astype(q.dtype)
@@ -517,9 +540,11 @@ def paged_append(kv: PagedKV, k_new: jax.Array, v_new: jax.Array) -> PagedKV:
     if is_global_layout(kv):
         # One scatter of B tokens into the shared pool; writes through NULL
         # table entries (inactive slots) are out-of-bounds and dropped.
+        idx = kv.pool_index(phys, slot)
+
         def write_g(pool, new):
-            return pool.at[phys, slot].set(new[:, 0].astype(pool.dtype),
-                                           mode="drop")
+            return pool.at[idx].set(new[:, 0].astype(pool.dtype),
+                                    mode="drop")
         return kv._replace(k_pool=write_g(kv.k_pool, k_new),
                            v_pool=write_g(kv.v_pool, v_new),
                            length=kv.length + 1)

@@ -352,9 +352,14 @@ def _prefill_write_global(kv: attn.PagedKV, k, v, ctx: FwdCtx, S: int
             gidx = jnp.clip(sidx, 0, max(S - 1, 0))
             kw = jnp.take_along_axis(kw, gidx[:, :, None, None], axis=1)
             kw = jnp.where(valid[:, :, None, None], kw, 0)
-        pages = kw.reshape(B, P_, page, *feat)
-        return pool.at[scatter_tbl.reshape(-1)].set(
-            pages.reshape(B * P_, page, *feat), mode="drop")
+        # Pages scatter as (page x Hkv, D) rows: a (page, Hkv, D) window
+        # makes the TPU compiler re-tile the pool, a copy of the whole
+        # stacked pool into and out of the layer loop; this view of the
+        # pool is a bitcast of its default layout.
+        rows = pool.reshape(*pool.shape[:-3], page * feat[0], feat[1])
+        return rows.at[kv.pool_index(scatter_tbl.reshape(-1))].set(
+            kw.reshape(B * P_, page * feat[0], feat[1]),
+            mode="drop").reshape(pool.shape)
 
     return kv._replace(k_pool=write(kv.k_pool, k),
                        v_pool=write(kv.v_pool, v),

@@ -173,8 +173,29 @@ def _maybe_remat(fn, cfg: ModelConfig, mode: str):
     return jax.checkpoint(fn, policy=policy)
 
 
+def _with_pools(blk_cache, pools, layer):
+    """``blk_cache`` with the KV at each pattern position in ``pools`` given
+    those (k_pool, v_pool) and ``layer`` as its ``PagedKV.layer``."""
+    out = dict(blk_cache)
+    for i, (k, v) in pools.items():
+        out[i] = {**out[i], "kv": out[i]["kv"]._replace(
+            k_pool=k, v_pool=v, layer=layer)}
+    return out
+
+
 def _run_blocks(cfg, params, x, ctx: FwdCtx, cache):
-    """Apply first_k_dense layers then the scanned block stack."""
+    """Apply first_k_dense layers then the scanned block stack.
+
+    The block cache's leaves carry a leading (n_blocks,) axis. Global-layout
+    KV pools (the zero-copy serving pool, ``attn.is_global_layout``) ride
+    the loop CARRY with a layer counter: each layer reads and writes the
+    stacked pool at ``[layer, page, slot]`` (``PagedKV.layer``), so the
+    loop updates it in place and a donated input pool is the output pool
+    (as ``xs``/``ys`` each layer's pool would be sliced out of the stack
+    and restacked: four whole-pool copies a call). Every other leaf (block
+    tables and lengths, per-slot pools, window rings, recurrent states,
+    cross-KV) rides ``xs``/``ys``.
+    """
     from repro.models.blocks import apply_layer
 
     new_cache: Dict[str, Any] = {} if cache is not None else None
@@ -190,28 +211,46 @@ def _run_blocks(cfg, params, x, ctx: FwdCtx, cache):
             new_cache["first"] = fc_out
 
     blk_cache = cache.get("blocks") if cache else None
+    pools = {i: (c["kv"].k_pool, c["kv"].v_pool)
+             for i, c in (blk_cache or {}).items()
+             if "kv" in c and attn.is_global_layout(c["kv"])}
+    no_pools = dict.fromkeys(pools, (None, None))
+    if pools:
+        blk_cache = _with_pools(blk_cache, no_pools, None)
 
-    def body(x, xs):
+    def body(carry, xs):
+        x, layer, pools = carry
         p_blk, c_blk = xs
+        if pools:
+            c_blk = _with_pools(c_blk, pools, layer)
         x, c_out = apply_block(p_blk, x, ctx, c_blk)
-        return x, c_out
+        if pools:
+            pools = {i: (c_out[i]["kv"].k_pool, c_out[i]["kv"].v_pool)
+                     for i in pools}
+            c_out = _with_pools(c_out, no_pools, None)
+        return (x, layer + 1, pools), c_out
 
     body = _maybe_remat(body, cfg, ctx.mode)
 
+    carry = (x, jnp.int32(0), pools)
     if cfg.scan_blocks and cfg.n_blocks > 1:
-        x, c_stack = jax.lax.scan(body, x, (params["blocks"], blk_cache))
+        carry, c_stack = jax.lax.scan(body, carry,
+                                      (params["blocks"], blk_cache))
     else:
         c_list = []
         for b in range(cfg.n_blocks):
             take = lambda t: jax.tree.map(lambda a: a[b], t)
-            x, c_out = body(x, (take(params["blocks"]),
-                                take(blk_cache) if blk_cache is not None else None))
+            carry, c_out = body(carry, (take(params["blocks"]),
+                                        take(blk_cache)
+                                        if blk_cache is not None else None))
             c_list.append(c_out)
         c_stack = (jax.tree.map(lambda *xs: jnp.stack(xs), *c_list)
                    if cache is not None and c_list and c_list[0] is not None
                    else None)
+    x, _, pools = carry
     if cache is not None:
-        new_cache["blocks"] = c_stack
+        new_cache["blocks"] = (_with_pools(c_stack, pools, None) if pools
+                               else c_stack)
     return x, new_cache
 
 

@@ -1,10 +1,13 @@
 """SVA layer + continuous-batching engine correctness."""
+from dataclasses import replace
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.configs import get_config, reduce_for_smoke
+from repro.configs.base import with_blocks
 from repro.core.sva.kv_manager import PagedKVManager
 from repro.core.sva.mapping import SVASpace
 from repro.core.sva.page_pool import PagePool
@@ -92,29 +95,53 @@ def _engine_outputs(mode, cfg, params, prompts, n=6):
     return [done[r].out_tokens for r in rids], eng.stats()
 
 
+def _manual_tokens(cfg, params, prompt, n=6):
+    """Greedy tokens of one prompt through a per-slot cache of its own:
+    prefill, then ``n - 1`` decode steps."""
+    cache = init_cache(cfg, 1, max_len=64, page_size=8, per_seq=True)
+    lg, cache = forward_prefill(
+        cfg, params, {"tokens": jnp.asarray(prompt, jnp.int32)[None]}, cache)
+    toks = [int(jnp.argmax(lg[0, -1]))]
+    pos = len(prompt)
+    for _ in range(n - 1):
+        lg, cache = forward_decode(
+            cfg, params, jnp.asarray([[toks[-1]]], jnp.int32),
+            jnp.asarray([pos], jnp.int32), cache)
+        toks.append(int(jnp.argmax(lg[0, 0])))
+        pos += 1
+    return toks
+
+
 def test_engine_matches_manual_loop(key):
     cfg = reduce_for_smoke(get_config("llama3.2-1b"))
     params = init_params(cfg, key)
     prompts = [[5, 9, 2, 14], [100, 7], [3, 3, 3, 8, 1, 30], [42]]
-
-    def manual(prompt, n=6):
-        cache = init_cache(cfg, 1, max_len=64, page_size=8, per_seq=True)
-        lg, cache = forward_prefill(
-            cfg, params, {"tokens": jnp.asarray(prompt, jnp.int32)[None]}, cache)
-        toks = [int(jnp.argmax(lg[0, -1]))]
-        pos = len(prompt)
-        for _ in range(n - 1):
-            lg, cache = forward_decode(
-                cfg, params, jnp.asarray([[toks[-1]]], jnp.int32),
-                jnp.asarray([pos], jnp.int32), cache)
-            toks.append(int(jnp.argmax(lg[0, 0])))
-            pos += 1
-        return toks
-
-    expected = [manual(p) for p in prompts]
+    expected = [_manual_tokens(cfg, params, p) for p in prompts]
     got, stats = _engine_outputs("zero_copy", cfg, params, prompts)
     assert got == expected
     assert stats["sva"]["bytes_copied"] == 0
+
+
+@pytest.mark.parametrize("scan_blocks", [True, False])
+def test_continuous_engine_pool_in_layer_carry(key, scan_blocks):
+    """Three blocks: the continuous engine's global pool rides the layer
+    loop's carry and is read and written at [layer, page, slot], scanned or
+    unrolled. Its tokens match the per-slot loop without a scan, whose
+    pools ride the loop's inputs and outputs (chunked prefill over 8-token
+    chunks, so later chunks read earlier ones back from the pool)."""
+    base = with_blocks(reduce_for_smoke(get_config("llama3.2-1b")), 3)
+    params = init_params(base, key)
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(0, base.vocab_size, size=k).tolist()
+               for k in (21, 6, 13, 9)]
+    ref_cfg = replace(base, scan_blocks=False)
+    expected = [_manual_tokens(ref_cfg, params, p) for p in prompts]
+    cfg = replace(base, scan_blocks=scan_blocks, sched_prefill_chunk=8)
+    eng = ServingEngine(cfg, params, n_slots=3, max_len=64, page_size=8,
+                        scheduler="continuous")
+    rids = [eng.submit(p, max_tokens=6) for p in prompts]
+    done = eng.run()
+    assert [done[r].out_tokens for r in rids] == expected
 
 
 def test_engine_copy_mode_same_tokens_more_copies(key):

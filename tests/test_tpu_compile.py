@@ -73,3 +73,57 @@ def test_paged_attention_per_slot_compiles_for_v5e(one_chip,
         S((B, HQ, D), jnp.bfloat16), pool, pool, S((B, 32), jnp.int32),
         S((B,), jnp.int32))
     assert "tpu_custom_call" in compiled.as_text()
+
+
+NB, SLOTS, TABLE, CARRY_POOL = 3, 8, 128, 4096   # 3 blocks, 8 x 2048 tokens
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_zero_copy_programs_hold_no_second_pool(one_chip, no_compile_cache,
+                                                program):
+    """The engine's decode and chunked-prefill programs over the stacked
+    global pool, for 3 scanned blocks at qwen2-7b attention widths: the
+    pool rides the layer loop's carry and is updated in place, so the
+    programs' temporaries stay below ONE layer's K pool. Sliced out of
+    the stack and restacked each layer, they held more than the whole
+    stacked pool."""
+    import functools
+    import types
+
+    from repro.configs.base import ModelConfig
+    from repro.core.serving.engine import ServingEngine
+    from repro.models import model_specs
+    from repro.models.dist import NO_MESH
+    from repro.models.model import cache_specs
+    from repro.models.params import abstract
+
+    cfg = ModelConfig(name="qwen2-7b-attn", family="dense", n_layers=NB,
+                      d_model=3584, n_heads=HQ, n_kv_heads=HKV, d_head=D,
+                      d_ff=512, vocab_size=1024, block_pattern=("attn_mlp",),
+                      qkv_bias=True, act="silu", param_dtype="bfloat16",
+                      activation_dtype="bfloat16")
+    S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    on_chip = lambda specs: jax.tree.map(lambda a: S(a.shape, a.dtype),
+                                         abstract(specs))
+    i32 = lambda *shape: S(shape, jnp.int32)
+    params = on_chip(model_specs(cfg))
+    cache = on_chip(cache_specs(cfg, SLOTS, TABLE * PAGE, PAGE, per_seq=True,
+                                global_pages=CARRY_POOL))
+    eng = types.SimpleNamespace(cfg=cfg, mi=NO_MESH, src_len=16,
+                                null_page=CARRY_POOL)
+    if program == "decode":
+        fn = jax.jit(functools.partial(ServingEngine._decode_masked, eng),
+                     donate_argnums=(5,))
+        args = (params, i32(SLOTS, 1), i32(SLOTS), i32(SLOTS, TABLE),
+                S((SLOTS,), jnp.bool_), cache)
+    else:
+        rows = 2
+        batch = {"tokens": i32(rows, 64), "lengths": i32(rows),
+                 "tables": i32(rows, TABLE), "slots": i32(rows),
+                 "prefix_lens": i32(rows), "write_tables": i32(rows, TABLE)}
+        fn = jax.jit(functools.partial(ServingEngine._prefill_zero_copy, eng),
+                     donate_argnums=(2,))
+        args = (params, batch, cache)
+    compiled = fn.lower(*args).compile()
+    layer_k_pool = CARRY_POOL * PAGE * HKV * D * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_k_pool
