@@ -3,10 +3,12 @@
   python bench/run.py --workload <cell> --seed <n> --seconds <s> --trace 0|1
 
 The cell (``BENCHMARK.json`` ``workloads``) names a configuration file
-(``bench/configs/<config>.json``) and a traffic mix
+(``bench/configs/<config>.json``), which names its architecture
+(``bench/arch/<reference>.py``, with its plain reference
+``bench/references/<reference>.py``), and a traffic mix
 (``bench/traffic/<traffic>.json``); each metric is read by
 ``bench/metrics/<metric>.py``. Nothing here names a cell, a configuration,
-a mix or a metric: adding one is adding its files.
+an architecture, a mix or a metric: adding one is adding its files.
 
 The run, in order: refuse anything but an accelerator with the chips the
 cell asks for; turn on JAX's persistent compilation cache; make the
@@ -211,17 +213,24 @@ def snapshot(eng) -> Dict[str, float]:
 
 def hook_scheduler(eng, log_: list) -> None:
     """Record what the scheduler composed for each step (decoding slots'
-    context lengths, chunk spans, prompt tokens admitted) and the KV pages
-    in use once it has admitted (pages mapped by live sequences, and pages
-    held in the pool, which adds the prefix cache's). Reads only; the
-    processing happens after the window."""
+    context lengths, chunk spans, prompt tokens admitted, and of those the
+    tokens whose prefill the prefix cache skipped: the step's part of the
+    counter ``ServingEngine.stats()`` reports as ``prefill_tokens_saved``)
+    and the KV pages in use once it has admitted (pages mapped by live
+    sequences, and pages held in the pool, which adds the prefix cache's).
+    Reads only; the processing happens after the window."""
     orig = eng.sched.schedule
     mgr = eng.mgr
 
+    def saved():
+        return mgr.prefix.stats.tokens_saved if mgr.prefix is not None else 0
+
     def schedule():
+        before = saved()
         out = orig()
         log_.append((out, mgr.lengths[out.decode_slots].copy(),
                      [len(eng._waiting_reqs[s].prompt) for s in out.admitted],
+                     saved() - before,
                      sum(len(st.pages) for st in mgr.seqs.values()),
                      mgr.pool.n_pages - mgr.pool.n_free))
         return out
@@ -229,10 +238,11 @@ def hook_scheduler(eng, log_: list) -> None:
 
 
 def sched_record(entry) -> dict:
-    out, lengths, admitted, live, held = entry
+    out, lengths, admitted, saved, live, held = entry
     return {"contexts": [int(x) for x in lengths],
             "chunks": [(c.start, c.end, c.is_final) for c in out.chunks],
             "admitted_tokens": int(sum(admitted)),
+            "saved_tokens": int(saved),
             "pages_live": int(live), "pages_held": int(held)}
 
 
@@ -271,10 +281,9 @@ def main(argv=None, t_start: Optional[float] = None,
 
     import jax
     from bench import metrics as M
-    from bench import work
     from bench.check import passed, run_check
     from bench.loop import EngineAdapter, run_window
-    from bench.model import load_config, make_weights, model_config
+    from bench.model import arch, load_config, make_weights
     from bench.traffic import load_mix, make_plan
     from repro.launch.serve import build_engine
 
@@ -282,7 +291,7 @@ def main(argv=None, t_start: Optional[float] = None,
     dev = devs[0]
     cfgj = load_config(cell["config"])
     mix = load_mix(cell["traffic"])
-    cfg = model_config(cfgj)
+    cfg = arch(cfgj).model_config(cfgj)
     sv = cfgj["serve"]
     params = jax.block_until_ready(
         make_weights(cfg, cfgj["initializer_range"], args.seed))
@@ -299,7 +308,8 @@ def main(argv=None, t_start: Optional[float] = None,
     c0 = snapshot(eng)
 
     tracer = Tracer(str(TRACE_DIR), args.seconds) if args.trace else None
-    setup_s = time.perf_counter() - t_start
+    # the window opens once the plan's warm-up has run: set-up too
+    setup_s = time.perf_counter() - t_start + plan.warm_s
     counter.active = True
     w = run_window(EngineAdapter(eng), plan, args.seconds,
                    span=tracer.span if tracer else
@@ -319,12 +329,23 @@ def main(argv=None, t_start: Optional[float] = None,
     peak = int(mem.get("peak_bytes_in_use", 0))
     lat = M.lateness_ms(w)
     recs = [s.sched for s in w.steps if s.sched is not None]
+    prefix = eng.mgr.prefix
     log(f"KV pool: {eng.mgr.pool.n_pages} pages of {eng.page_size} tokens; "
         f"peak in the window {max((r['pages_live'] for r in recs), default=0)}"
         f" mapped by live sequences, "
         f"{max((r['pages_held'] for r in recs), default=0)} held with the "
-        f"prefix cache's")
-    log(f"window: {args.seconds} s, {len(w.records)} requests due, "
+        f"prefix cache's; "
+        f"{prefix.n_cached_pages if prefix is not None else 0} pages in the "
+        f"prefix cache at the close")
+
+    def nbytes(tree):
+        return sum(x.nbytes for x in jax.tree_util.tree_leaves(tree))
+    log(f"memory: peak {peak} B in use of {mem.get('bytes_limit')}; weights "
+        f"{nbytes(params)} B, KV pool {nbytes(eng.cache)} B")
+    done = sum(1 for r in w.records + w.warm if r.out is not None
+               and 0 <= r.times[-1] < w.seconds)
+    log(f"window: {args.seconds} s after {plan.warm_s} s of warm-up, "
+        f"{len(w.records)} requests due, {done} finished in it, "
         f"{len(w.steps)} steps, drain {w.drain_s:.3f} s; in-window "
         f"compiles={counter.counts[COMPILE_EVENTS[0]]} "
         f"traces={counter.counts[COMPILE_EVENTS[1]]}; generator lateness "
@@ -337,8 +358,7 @@ def main(argv=None, t_start: Optional[float] = None,
 
     trace = tracer.reduce() if tracer else None
 
-    dims = work.Dims.from_config(cfgj)
-    ctx = M.Ctx(window=w, counters=counters, dims=dims,
+    ctx = M.Ctx(window=w, counters=counters, work=arch(cfgj).counts(cfgj),
                 peaks=peaks_for(dev.device_kind) if args.trace else None,
                 trace=trace,
                 extra={"setup_s": setup_s,

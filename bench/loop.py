@@ -4,11 +4,19 @@ every output token on the host clock.
 The engine is driven one ``step`` at a time. Before each step the loop
 sends every request that is due; a request's latency counts from its due
 time, not from when the loop got round to sending it, and the loop records
-how late it sent each one. After each step it reads how many tokens each
-request in flight has, and stamps the new ones with the time the step
-returned (the served tokens are on the host by then: the engine's argmax is
-read back inside the step). When nothing is in flight it sleeps until the
-next request is due.
+how late it sent each one. A request is due at a time the plan fixed in
+advance (an open loop), or when an earlier one finishes: the plan's
+``after`` gives the requests that a finished or refused one makes due
+(a closed loop's next request, a session's next turn). After each step it
+reads how many tokens each request in flight has, and stamps the new ones
+with the time the step returned (the served tokens are on the host by
+then: the engine's argmax is read back inside the step). When nothing is
+in flight it sleeps until the next request is due.
+
+A plan may ask for a warm-up (``warm_s``): the loop then runs that long
+before the window opens, at negative window times, so that the window
+sees the loop's steady state and not its start. Requests due then are
+kept apart (``warm``); their tokens inside the window count.
 
 At the window's close no more requests are sent. The loop then keeps
 stepping until every request that was due in the window has its first
@@ -19,6 +27,7 @@ count only inside the window.
 from __future__ import annotations
 
 import contextlib
+import heapq
 import time
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional
@@ -51,9 +60,10 @@ class StepRec:
 @dataclass
 class WindowResult:
     seconds: float
-    records: List[Record]
+    records: List[Record]                # requests due in the window
     steps: List[StepRec]                 # steps that started in the window
     drain_s: float
+    warm: List[Record] = field(default_factory=list)   # due before it
 
 
 class EngineAdapter:
@@ -100,39 +110,49 @@ def run_window(eng: EngineAdapter, plan: Plan, seconds: float, *,
                sched_log: Optional[list] = None,
                on_time: Optional[Callable[[float], None]] = None
                ) -> WindowResult:
-    """Run the window: the plan's due times drive an open loop.
+    """Run the window: the plan's due times drive the loop.
     ``sched_log`` is appended to once per engine step by the harness's
     scheduler hook; ``on_time(now)`` is called between steps (the harness
     starts and stops its trace from it)."""
     records: Dict[int, Record] = {}
-    due = sorted(plan.requests, key=lambda r: (r.due, r.rid))[::-1]
+    due = [(r.due, r.rid, r) for r in plan.first()]
+    heapq.heapify(due)
     inflight: Dict[int, Record] = {}            # erid -> record
     seen: Dict[int, int] = {}
     steps: List[StepRec] = []
+
+    def follow(req, out, t):
+        for r in plan.after(req, out, t):
+            heapq.heappush(due, (r.due, r.rid, r))
     t0 = clock()
-    now = 0.0
+
+    def at():               # window time: it opens after the warm-up
+        return clock() - t0 - plan.warm_s
+    now = -plan.warm_s
     closed_at = None
 
     while True:
-        now = clock() - t0
+        now = at()
         if on_time is not None:
             on_time(now)
         if closed_at is None and now >= seconds:
             closed_at = now
         if closed_at is None:
             sent = False
-            while due and due[-1].due <= now:
-                r = due.pop()
+            while due and due[0][0] <= now:
+                r = heapq.heappop(due)[2]
                 rec = records[r.rid] = Record(r)
                 with span("bench.submit"):
                     try:
                         rec.erid = eng.submit(r.prompt, r.max_tokens)
                     except Exception as e:          # refused: a failure
                         rec.failed = f"{type(e).__name__}: {e}"
-                rec.submit = clock() - t0
+                rec.submit = at()
                 if rec.erid is not None:
                     inflight[rec.erid] = rec
                     seen[rec.erid] = 0
+                else:
+                    follow(r, None, rec.submit)
                 sent = True
             if sent:
                 continue
@@ -144,8 +164,8 @@ def run_window(eng: EngineAdapter, plan: Plan, seconds: float, *,
             start = now
             with span(f"bench.step#{len(steps)}"):
                 eng.step()
-            end = clock() - t0
-            if closed_at is None:
+            end = at()
+            if closed_at is None and start >= 0:
                 steps.append(StepRec(start, end,
                                      sched_log[-1] if sched_log else None))
             for erid, rec in list(inflight.items()):
@@ -156,8 +176,9 @@ def run_window(eng: EngineAdapter, plan: Plan, seconds: float, *,
                 if erid in eng.finished:
                     del inflight[erid]
                     rec.out = eng.output(erid)
+                    follow(rec.req, rec.out, end)
         elif closed_at is None:
-            nxt = due[-1].due if due else seconds
+            nxt = due[0][0] if due else seconds
             with span("bench.wait"):
                 sleep(max(0.0, min(nxt, seconds) - now))
         else:
@@ -165,5 +186,7 @@ def run_window(eng: EngineAdapter, plan: Plan, seconds: float, *,
     for rec in inflight.values():
         if not rec.times:
             rec.failed = rec.failed or "no first token by the end of the drain"
-    return WindowResult(seconds, list(records.values()), steps,
-                        (now - closed_at) if closed_at is not None else 0.0)
+    return WindowResult(
+        seconds, [r for r in records.values() if r.req.due >= 0], steps,
+        (now - closed_at) if closed_at is not None else 0.0,
+        [r for r in records.values() if r.req.due < 0])

@@ -19,7 +19,7 @@ HERE = pathlib.Path(__file__).resolve().parent
 class Ctx:
     window: Any                    # loop.WindowResult
     counters: Dict[str, float]     # engine counters, window delta
-    dims: Any                      # work.Dims
+    work: Any                      # the architecture's counts(c)
     peaks: Any                     # peaks.Peaks (None off a known chip)
     trace: Any = None              # profile.Trace of the traced window
     extra: Dict[str, Any] = field(default_factory=dict)
@@ -57,9 +57,10 @@ def gaps_ms(w) -> List[float]:
     """Every gap between consecutive output tokens of one request whose later
     token came inside the window."""
     out = []
-    for r in w.records:
+    for r in w.records + w.warm:
         t = r.times
-        out.extend((b - a) * 1e3 for a, b in zip(t, t[1:]) if b < w.seconds)
+        out.extend((b - a) * 1e3 for a, b in zip(t, t[1:])
+                   if 0 <= b < w.seconds)
     return out
 
 

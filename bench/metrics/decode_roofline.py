@@ -1,8 +1,8 @@
 """Share of its roofline that the decode program reaches in the traced
 window: the least time the chip needs for each decode step (the larger of
 its FLOPs over peak FLOP/s and the bytes it must move over peak HBM
-bytes/s, from ``bench/work.py``), summed, over the program's device time."""
-from bench import work
+bytes/s, from the configuration's architecture,
+``bench/arch/<reference>.py``), summed, over the program's device time."""
 from bench.profile import step_of
 
 
@@ -18,7 +18,7 @@ def read(ctx):
         contexts = steps[i].sched["contexts"]
         if not contexts:
             continue
-        flops, nbytes = work.decode_step(ctx.dims, contexts)
+        flops, nbytes = ctx.work.decode_step(contexts)
         need += max(flops / ctx.peaks.bf16_flops, nbytes / ctx.peaks.hbm_bytes)
         spent += ev.dur / 1e9
     return 100.0 * need / spent if spent else None

@@ -1,8 +1,8 @@
 """Model FLOP utilization of the whole engine step in the traced window:
 useful FLOPs of the steps (decodes over their live lengths, prefill chunks
 over their context, the LM head where logits are taken; no padding, from
-``bench/work.py``) over the steps' host-span time times peak bf16 FLOP/s."""
-from bench import work
+the configuration's architecture, ``bench/arch/<reference>.py``) over the
+steps' host-span time times peak bf16 FLOP/s."""
 
 
 def read(ctx):
@@ -14,6 +14,6 @@ def read(ctx):
         if i >= len(steps) or steps[i].sched is None:
             continue
         s = steps[i].sched
-        flops += work.step_flops(ctx.dims, s["contexts"], s["chunks"])
+        flops += ctx.work.step_flops(s["contexts"], s["chunks"])
         secs += span.dur / 1e9
     return 100.0 * flops / (secs * ctx.peaks.bf16_flops) if secs else None

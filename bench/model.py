@@ -1,12 +1,17 @@
-"""A configuration file as the program's ``ModelConfig``, and its weights.
+"""A configuration file, the program's ``ModelConfig`` for it, and its weights.
 
-The configuration files of ``bench/configs`` use the published config's
-own key names. The weights are random, made from ``--seed`` on the device
-in one jitted call, in the dtype they are served in: normal with the
-published ``initializer_range`` for every weight and bias, norms at 1.
+A configuration file (``bench/configs/<config>.json``) names its
+architecture by its ``"reference"`` key: ``bench/arch/<reference>.py``
+turns the file into the program's ``ModelConfig`` and counts its steps,
+and ``bench/references/<reference>.py`` is its plain reference. Nothing
+here knows an architecture. The weights are random, made from ``--seed``
+on the device in one jitted call, in the dtype they are served in: normal
+with the published ``initializer_range`` for every weight and bias, norms
+at 1.
 """
 from __future__ import annotations
 
+import importlib
 import json
 import pathlib
 from typing import Any, Optional
@@ -19,22 +24,11 @@ def load_config(name: str, directory: Optional[pathlib.Path] = None) -> dict:
     return json.loads(((directory or CONFIG_DIR) / f"{name}.json").read_text())
 
 
-def model_config(c: dict):
-    """The program's ``ModelConfig`` for a configuration file."""
-    from repro.configs.base import ModelConfig
-    d, h = c["hidden_size"], c["num_attention_heads"]
-    if c["hidden_act"] != "silu":
-        raise ValueError(f"{c['name']}: hidden_act {c['hidden_act']!r}")
-    return ModelConfig(
-        name=c["name"], family="dense", n_layers=c["num_hidden_layers"],
-        d_model=d, n_heads=h, n_kv_heads=c["num_key_value_heads"],
-        d_head=c.get("head_dim") or d // h, d_ff=c["intermediate_size"],
-        vocab_size=c["vocab_size"], block_pattern=("attn_mlp",),
-        qkv_bias=bool(c["architecture"]["qkv_bias"]),
-        rope_theta=float(c["rope_theta"]), act="silu",
-        norm_eps=float(c["rms_norm_eps"]),
-        tie_embeddings=bool(c["tie_word_embeddings"]),
-        param_dtype=c["torch_dtype"], activation_dtype=c["torch_dtype"])
+def arch(c: dict):
+    """The architecture module a configuration file names by its
+    ``"reference"`` key, ``bench/arch/<reference>.py``: its
+    ``model_config(c)`` and ``counts(c)``."""
+    return importlib.import_module(f"bench.arch.{c['reference']}")
 
 
 def seed_key(seed: int):

@@ -62,7 +62,10 @@ class Trace:
                 max(e.end for e in self.spans))
 
     def program(self, fn: str) -> List[Ev]:
-        """Executions of the jitted function ``fn`` inside the window."""
+        """Executions of the jitted function ``fn`` inside the window
+        (none where no harness span was recorded)."""
+        if not self.spans:
+            return []
         lo, hi = self.window()
         pat = re.compile(r"^jit_" + re.escape(fn) + r"\(")
         return [e for e in self.modules

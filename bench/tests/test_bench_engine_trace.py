@@ -51,7 +51,7 @@ def engine_hand_trace():
 
 
 def ctx_of(tr, counters=None):
-    return M.Ctx(window=None, counters=counters or {}, dims=None, peaks=None,
+    return M.Ctx(window=None, counters=counters or {}, work=None, peaks=None,
                  trace=tr)
 
 

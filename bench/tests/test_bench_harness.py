@@ -2,6 +2,8 @@
 ``launch.serve.build_engine``. The tests steer to the CPU themselves: they
 hand ``harness.main`` the CPU device in place of its look for a chip."""
 import json
+import pathlib
+import re
 import shutil
 import subprocess
 import sys
@@ -24,6 +26,23 @@ MIX = {"name": "tiny-open", "loop": "open", "rate_per_s": 4.0,
                   "min": 8, "max": 96},
        "output": {"dist": "lognormal", "median": 8, "sigma": 0.5,
                   "min": 4, "max": 24}}
+LOOPS = {
+    "closed": {"name": "tiny-closed", "loop": "closed", "clients": 6,
+               "warm_s": 0.5, "requests": 1024, "bands": 4,
+               "prompt": MIX["prompt"], "output": MIX["output"]},
+    "sessions": {"name": "tiny-sessions", "loop": "sessions",
+                 "rate_per_s": 3.0, "bands": 4,
+                 "system": {"count": 3, "tokens": 40, "zipf": 1.1},
+                 "turns": {"dist": "uniform", "min": 2, "max": 4},
+                 "user": {"dist": "uniform", "min": 4, "max": 20},
+                 "output": {"dist": "lognormal", "median": 6, "sigma": 0.5,
+                            "min": 2, "max": 12},
+                 "think_s": 0.2},
+}
+TOY = {"name": "toy", "reference": "toy", "width": 64, "mlp_width": 96,
+       "heads": 4, "kv_heads": 2, "depth": 2, "vocab": 384, "theta": 1e4,
+       "eps": 1e-6, "initializer_range": 0.02,
+       "serve": TINY["serve"], "check": TINY["check"]}
 NEW_METRIC = '''"""Engine steps per window second (a metric added as one file)."""
 
 
@@ -43,15 +62,19 @@ def tree(tmp_path_factory):
     cfg = json.loads((BENCH / "configs" / "qwen2-7b.json").read_text())
     cfg.update(TINY)
     (root / "configs" / "tiny.json").write_text(json.dumps(cfg))
+    (root / "configs" / "toy.json").write_text(json.dumps(TOY))
     (root / "traffic" / "tiny-open.json").write_text(json.dumps(MIX))
+    for mix in LOOPS.values():
+        (root / "traffic" / f"{mix['name']}.json").write_text(json.dumps(mix))
     (root / "metrics" / "tiny.steps_per_s.py").write_text(NEW_METRIC)
     b = json.loads((harness.ROOT / "BENCHMARK.json").read_text())
-    b["workloads"].append({"name": "tiny.open", "config": "tiny",
-                           "traffic": "tiny-open", "chips": 1,
-                           "why": "CPU rehearsal"})
+    cells = ["tiny.open"] + [f"tiny.{k}" for k in LOOPS] + ["toy.open"]
+    b["workloads"] += [{"name": c, "config": c.split(".")[0],
+                        "traffic": "tiny-" + c.split(".")[1], "chips": 1,
+                        "why": "CPU rehearsal"} for c in cells]
     for m in b["end_to_end"] + b["per_layer"]:
         if "workloads" in m:
-            m["workloads"].append("tiny.open")
+            m["workloads"] += cells
     b["per_layer"].append({"name": "tiny.steps_per_s", "unit": "1/s",
                            "better": "higher", "source": "host_clock",
                            "layer": "engine step", "moves": "itl_p50_ms",
@@ -60,12 +83,14 @@ def tree(tmp_path_factory):
     return root
 
 
-def run_cell(tree, monkeypatch, capsys, trace=0, seed=2**32 + 11, extra=()):
-    return run_cell_io(tree, monkeypatch, capsys, trace, seed, extra)[0]
+def run_cell(tree, monkeypatch, capsys, trace=0, seed=2**32 + 11, extra=(),
+             cell="tiny.open"):
+    return run_cell_io(tree, monkeypatch, capsys, trace, seed, extra,
+                       cell)[0]
 
 
 def run_cell_io(tree, monkeypatch, capsys, trace=0, seed=2**32 + 11,
-                extra=()):
+                extra=(), cell="tiny.open"):
     """The result line and the standard error of one run."""
     monkeypatch.setattr(harness, "ROOT", tree)
     monkeypatch.setattr(model, "CONFIG_DIR", tree / "configs")
@@ -73,7 +98,7 @@ def run_cell_io(tree, monkeypatch, capsys, trace=0, seed=2**32 + 11,
     monkeypatch.setattr(metrics, "HERE", tree / "metrics")
     monkeypatch.setattr(harness, "TRACE_DIR", tree / "traces")
     monkeypatch.setattr(harness, "peaks_for", lambda k: PEAKS["TPU v5 lite"])
-    rc = harness.main(["--workload", "tiny.open", "--seed", str(seed),
+    rc = harness.main(["--workload", cell, "--seed", str(seed),
                        "--seconds", "2", "--trace", str(trace), *extra],
                       devices_fn=lambda n: jax.devices("cpu")[:n],
                       cache_fn=lambda: "off")
@@ -94,6 +119,54 @@ def test_rehearsal_last_line_and_new_cell(tree, monkeypatch, capsys):
         if "tiny.open" in m.get("workloads", ["tiny.open"])}
     assert res["device"]["platform"] == "cpu"
     assert set(res["check"]["max_logit_gap"]) == {"value", "limit"}
+
+
+@pytest.mark.parametrize("loop", sorted(LOOPS))
+def test_rehearsal_of_the_other_loops(tree, monkeypatch, capsys, loop):
+    """A closed loop and sessions through the engine: every request sent
+    is served in full and checked, and sessions' later turns find their
+    history in the prefix cache."""
+    res = run_cell(tree, monkeypatch, capsys, trace=1, cell=f"tiny.{loop}")
+    assert res["correct"] is True and res["failed"] == 0
+    assert res["check"]["wrong_lengths"]["value"] == 0
+    hit = res["metrics"].get("kv.prefix_hit_pct")
+    if loop == "sessions":
+        assert 0 < hit["value"] < 100
+    else:
+        assert res["attempted"] > LOOPS[loop]["clients"]
+
+
+def test_a_new_architecture_enters_as_new_files(tree, monkeypatch, capsys):
+    """An architecture module and its reference in a directory of their own
+    (here the tests'), named by a configuration file's ``reference``: the
+    harness builds the program's model from it, and the metric readers
+    count the steps by its rule."""
+    import bench.arch
+    import bench.references
+    toy = pathlib.Path(__file__).resolve().parent / "toy"
+    monkeypatch.setattr(bench.arch, "__path__",
+                        [*bench.arch.__path__, str(toy / "arch")])
+    monkeypatch.setattr(bench.references, "__path__",
+                        [*bench.references.__path__, str(toy / "references")])
+    from bench.arch import toy as arch
+    arch.CALLS.clear()
+    res = run_cell(tree, monkeypatch, capsys, trace=1, cell="toy.open")
+    assert res["correct"] is True and res["failed"] == 0
+    assert [c[0] for c in arch.CALLS[:2]] == ["model_config", "counts"]
+    # mfu_pct from the toy's rule (decode_roofline needs the device's
+    # program events, which a CPU trace lacks)
+    steps = [c for c in arch.CALLS if c[0] == "step_flops"]
+    assert steps and any(c[1] for c in steps) and any(c[2] for c in steps)
+    assert res["metrics"]["mfu_pct"]["value"] > 0
+
+
+def test_only_architecture_modules_name_an_architecture():
+    """The harness, the model loader and the metric readers know no
+    architecture: that is ``bench/arch``'s and ``bench/references``'."""
+    names = re.compile(r"Dims|attn_mlp|family=")
+    files = [*BENCH.glob("*.py"), *(BENCH / "metrics").glob("*.py")]
+    assert files
+    assert [f.name for f in files if names.search(f.read_text())] == []
 
 
 def test_float8_control_fails_the_limit(tree, monkeypatch, capsys):
@@ -148,6 +221,25 @@ def test_decode_that_returns_its_cache_unchanged_is_not_correct(
         return logits, a[-1]
     monkeypatch.setattr(ServingEngine, "_decode_masked", stale)
     res = run_cell(tree, monkeypatch, capsys)
+    assert res["correct"] is False
+    gap = res["check"]["max_logit_gap"]
+    assert gap["value"] > gap["limit"]
+
+
+def test_prefill_that_skips_the_mapped_prefix_is_not_correct(
+        tree, monkeypatch, capsys):
+    """The timed path broken underneath, in sessions: a prefill chunk no
+    longer reads the prefix that the cache mapped (or that earlier chunks
+    wrote), so every later turn is served from its new tokens alone."""
+    from repro.core.serving.engine import ServingEngine
+    orig = ServingEngine._prefill_zero_copy
+
+    def blind(self, params, batch, cache):
+        batch = dict(batch, prefix_lens=jax.numpy.zeros_like(
+            batch["prefix_lens"]))
+        return orig(self, params, batch, cache)
+    monkeypatch.setattr(ServingEngine, "_prefill_zero_copy", blind)
+    res = run_cell(tree, monkeypatch, capsys, cell="tiny.sessions")
     assert res["correct"] is False
     gap = res["check"]["max_logit_gap"]
     assert gap["value"] > gap["limit"]

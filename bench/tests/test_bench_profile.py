@@ -31,7 +31,7 @@ def test_union_and_busy():
     assert busy_ns(tr, 0, 300) == 50 + 80
     # idle only counts inside steps: 200 ns of steps, 130 busy
     assert step_busy(tr) == (130, 200)
-    ctx = M.Ctx(window=None, counters={}, dims=None, peaks=None, trace=tr)
+    ctx = M.Ctx(window=None, counters={}, work=None, peaks=None, trace=tr)
     assert M.reader("device.idle_pct")(ctx) == pytest.approx(35.0)
 
 
@@ -40,7 +40,7 @@ def test_programs_and_steps():
     dec = tr.program("_decode_masked")
     assert [e.dur for e in dec] == [50, 40]
     assert [step_of(tr, e) for e in dec] == [0, 1]
-    ctx = M.Ctx(window=None, counters={}, dims=None, peaks=None, trace=tr)
+    ctx = M.Ctx(window=None, counters={}, work=None, peaks=None, trace=tr)
     assert M.reader("decode.device_ms_per_step")(ctx) == pytest.approx(45e-6)
     assert M.reader("prefill.device_ms_per_call")(ctx) == pytest.approx(4e-5)
 
@@ -76,7 +76,7 @@ def test_recorded_v5e_trace():
     # and one prefill call
     assert len(dec) == 8 and len(tr.program("_prefill_zero_copy")) == 8
     assert (busy, total) == (730681362, 759554735)
-    ctx = M.Ctx(window=None, counters={}, dims=None, peaks=None, trace=tr)
+    ctx = M.Ctx(window=None, counters={}, work=None, peaks=None, trace=tr)
     assert M.reader("decode.device_ms_per_step")(ctx) == \
         pytest.approx(38.025296875)
     assert M.reader("prefill.device_ms_per_call")(ctx) == \

@@ -1,27 +1,31 @@
-"""FLOPs and bytes from shapes, against hand counts: the committed
-qwen2-7b configuration (GQA with QKV bias) and DeepSeek-LLM-7B's published
-widths cut to 10 layers (full multi-head attention, no bias)."""
+"""FLOPs and bytes from shapes, against hand counts, through the LLaMA-family
+architecture module ``bench/arch/llama.py``: the committed qwen2-7b
+configuration (GQA with QKV bias) and deepseek-llm-7b (published widths
+cut to 10 layers; full multi-head attention, no bias)."""
 import pytest
 
-from bench import work
-from bench.model import load_config
+from bench.arch import llama
+from bench.model import arch, load_config
 
-
-DEEPSEEK_7B = {"hidden_size": 4096, "num_attention_heads": 32,
-               "num_key_value_heads": 32, "num_hidden_layers": 10,
-               "intermediate_size": 11008, "vocab_size": 102400,
-               "architecture": {"qkv_bias": False}}
+HAND = {
+    "qwen2-7b": llama.Dims(layers=14, d_model=3584, n_heads=28, n_kv_heads=4,
+                           d_head=128, d_ff=18944, vocab=152064,
+                           qkv_bias=True),
+    "deepseek-llm-7b": llama.Dims(layers=10, d_model=4096, n_heads=32,
+                                  n_kv_heads=32, d_head=128, d_ff=11008,
+                                  vocab=102400, qkv_bias=False),
+}
 
 
 def dims(name):
-    if name == "deepseek-llm-7b":
-        return work.Dims(layers=10, d_model=4096, n_heads=32, n_kv_heads=32,
-                         d_head=128, d_ff=11008, vocab=102400, qkv_bias=False)
-    return work.Dims.from_config(load_config(name))
+    c = load_config(name)
+    assert arch(c) is llama
+    return arch(c).counts(c)
 
 
-def test_from_config_reads_the_published_keys():
-    assert work.Dims.from_config(DEEPSEEK_7B) == dims("deepseek-llm-7b")
+@pytest.mark.parametrize("name", sorted(HAND))
+def test_from_config_reads_the_published_keys(name):
+    assert dims(name) == HAND[name]
 
 
 def test_qwen2_7b_counts():
@@ -45,7 +49,7 @@ def test_deepseek_counts():
 def test_decode_step_by_hand():
     d = dims("deepseek-llm-7b")
     ctx = [100, 2000]
-    f, b = work.decode_step(d, ctx)
+    f, b = d.decode_step(ctx)
     per_tok = 2 * 10 * d.layer_matmul_params + 2 * 4096 * 102400
     attn = 4 * 10 * 32 * 128 * (100 + 2000)
     assert f == 2 * per_tok + attn
@@ -57,9 +61,9 @@ def test_decode_step_by_hand():
 def test_prefill_chunk_by_hand():
     d = dims("qwen2-7b")
     # positions 64..127 attend over 65..128 keys: sum = 6240
-    f = work.prefill_chunk_flops(d, 64, 128, final=False)
+    f = d.prefill_chunk_flops(64, 128, final=False)
     assert f == 64 * 2 * 14 * d.layer_matmul_params \
         + 4 * 14 * 28 * 128 * sum(range(65, 129))
-    g = work.prefill_chunk_flops(d, 64, 128, final=True)
+    g = d.prefill_chunk_flops(64, 128, final=True)
     assert g - f == 2 * 3584 * 152064
-    assert work.step_flops(d, [], [(64, 128, True)]) == g
+    assert d.step_flops([], [(64, 128, True)]) == g
