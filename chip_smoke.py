@@ -9,9 +9,10 @@ weights plus the page pool exceed one v5e's HBM), makes the weights from
 ``--seed`` on the device, and serves seeded requests through the engine
 ``launch/serve.py`` builds: the continuous
 scheduler over the global zero-copy page pool. It serves them twice, with
-the pure-JAX decode attention and with the Pallas paged-decode kernel, then
-checks the kernel against a float32 reference on the live pool, tables and
-lengths, and that the compiled decode step holds the kernel. Every wall time
+the pure-JAX decode attention forced and with the ragged paged-decode
+kernel the engine chooses on a TPU, then checks the kernel against a
+float32 reference on the live pool, tables and lengths, and that the
+compiled decode step holds the kernel. Every wall time
 ends in ``block_until_ready`` and names the device; every other number is a
 count. The last line of standard output is
 ``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``.
@@ -19,6 +20,7 @@ count. The last line of standard output is
 from __future__ import annotations
 
 import argparse
+import contextlib
 import gc
 import json
 import pathlib
@@ -111,13 +113,26 @@ def serve(eng, prompts, max_tokens: int, *, snapshot: bool = False):
     return [finished[r].out_tokens for r in rids], snap
 
 
+@contextlib.contextmanager
+def jax_decode_path():
+    """Engines built and served inside take the pure-JAX decode attention:
+    the one choice of path (``attention.ragged_decode_serves``), forced."""
+    from repro.models import attention
+    serves = attention.ragged_decode_serves
+    attention.ragged_decode_serves = lambda kv: False
+    try:
+        yield
+    finally:
+        attention.ragged_decode_serves = serves
+
+
 def within_tol(out, ref) -> bool:
     return bool(np.allclose(out, ref, rtol=KERNEL_TOL, atol=KERNEL_TOL))
 
 
 def kernel_check(snap, n_heads: int, seed: int) -> dict:
-    """Pallas paged decode on a live pool vs the float32 reference, on the
-    decoding slots. Also reports how far the served pure-JAX decode
+    """The ragged paged decode on a live pool vs the float32 reference, on
+    the decoding slots. Also reports how far the served pure-JAX decode
     attention, at default precision, lands from the same reference."""
     import jax.numpy as jnp
     from repro.kernels.paged_attention.ops import paged_decode
@@ -131,8 +146,9 @@ def kernel_check(snap, n_heads: int, seed: int) -> dict:
     q = jax.random.normal(jax.random.key(seed), (B, n_heads, D)) \
         .astype(k_pool.dtype).astype(jnp.float32)
     out = paged_decode(q, k_pool, v_pool, tables, lengths)
-    served_jax = paged_decode_attention(
-        q[:, None], PagedKV(k_pool, v_pool, tables, lengths))[:, 0]
+    with jax_decode_path():
+        served_jax = paged_decode_attention(
+            q[:, None], PagedKV(k_pool, v_pool, tables, lengths))[:, 0]
     with jax.default_matmul_precision("highest"):
         ref = paged_attention_global_ref(
             q, k_pool.astype(jnp.float32), v_pool.astype(jnp.float32),
@@ -208,12 +224,13 @@ def main(argv=None) -> int:
 
     outs = {}
     for backend in ("jax", "pallas"):
-        eng = build_engine(cfg, params, slots=SLOTS, max_len=MAX_LEN,
-                           page_size=PAGE, pool_pages=pool_pages,
-                           decode_backend=backend)
-        t0 = time.perf_counter()
-        outs[backend], snap = serve(eng, prompts, MAX_TOKENS,
-                                    snapshot=backend == "pallas")
+        with (jax_decode_path() if backend == "jax"
+              else contextlib.nullcontext()):
+            eng = build_engine(cfg, params, slots=SLOTS, max_len=MAX_LEN,
+                               page_size=PAGE, pool_pages=pool_pages)
+            t0 = time.perf_counter()
+            outs[backend], snap = serve(eng, prompts, MAX_TOKENS,
+                                        snapshot=backend == "pallas")
         wall = time.perf_counter() - t0
         check_lengths(outs[backend], MAX_TOKENS)
         s = eng.stats()

@@ -94,10 +94,6 @@ class ModelConfig:
     flash_q_chunk: int = 512        # flash attention q block
     flash_kv_chunk: int = 1024      # flash attention kv block
     use_pallas: bool = False        # opt-in TPU kernels (CPU uses pure-JAX paths)
-    # decode-attention backend: "jax" (pure-JAX gather path) or "pallas"
-    # (kernels/paged_attention scalar-prefetch kernel on the decode hot
-    # path — interpret-mode off-TPU, real kernel on TPU).
-    decode_backend: str = "jax"
     # Warm prefix-cache tuning (serving): eviction policy of the
     # cross-request PrefixIndex and an optional cap on the pages it may
     # retain after release (0 = bounded only by pool pressure). gdsfs is
@@ -147,10 +143,6 @@ class ModelConfig:
     def __post_init__(self):
         if self.d_head == 0:
             object.__setattr__(self, "d_head", self.d_model // self.n_heads)
-        if self.decode_backend not in ("jax", "pallas"):
-            raise ValueError(
-                f"{self.name}: decode_backend={self.decode_backend!r} "
-                "(expected 'jax' or 'pallas')")
         if self.prefix_cache_policy not in ("lru", "lfu", "gdsfs"):
             raise ValueError(
                 f"{self.name}: prefix_cache_policy="

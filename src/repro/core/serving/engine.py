@@ -33,10 +33,11 @@ before the next prefill/decode touches the page. Completed requests leave
 their prompt pages behind as a warm prefix cache (LRU-evicted under page
 pressure).
 
-The decode hot path can run through the Pallas scalar-prefetch kernel
-(``decode_backend="pallas"`` — kernels/paged_attention, interpret-mode off
-TPU): the per-slot block tables live in SMEM and drive the KV page DMAs,
-so gathering through *shared* block tables costs the same as private ones.
+On a TPU the decode hot path runs through the ragged Pallas kernel
+(kernels/paged_attention, chosen by ``attention.ragged_decode_serves``):
+the per-slot block tables live in SMEM and drive the DMAs of each row's
+live KV pages, so reading through *shared* block tables costs the same as
+private ones.
 
 Adaptive translation front-end (``ModelConfig.serve_tlb_prefetch_*`` /
 ``serve_tlb_autotune*``; both default-off): the engine arms the manager's
@@ -52,7 +53,6 @@ passing a MeshInfo.
 """
 from __future__ import annotations
 
-import dataclasses
 import time
 from collections import deque
 from dataclasses import dataclass, field
@@ -256,7 +256,6 @@ class ServingEngine:
                  offload_mode: str = "zero_copy", src_len: int = 16,
                  eos_token: Optional[int] = None,
                  prefix_sharing: bool = True,
-                 decode_backend: Optional[str] = None,
                  record_translation_trace: bool = False,
                  translation_stats: bool = False,
                  scheduler: str = "fixed",
@@ -265,8 +264,6 @@ class ServingEngine:
         if scheduler not in ("fixed", "continuous"):
             raise ValueError(f"scheduler={scheduler!r} "
                              "(expected 'fixed' or 'continuous')")
-        if decode_backend is not None:
-            cfg = dataclasses.replace(cfg, decode_backend=decode_backend)
         self.scheduler_mode = scheduler
         self.cfg, self.params, self.mi = cfg, params, mi
         self.n_slots, self.max_len, self.page_size = n_slots, max_len, page_size
@@ -362,7 +359,10 @@ class ServingEngine:
                         "table_rows_uploaded": 0, "table_upload_bytes": 0,
                         "admit_table_bytes": 0,
                         "prefill_tokens_saved": 0, "shared_admissions": 0,
-                        "cow_page_copies": 0}
+                        "cow_page_copies": 0,
+                        # KV pages the decoding rows hold, summed over
+                        # decode steps: what the decode attention reads
+                        "decode_kv_pages": 0}
 
         if offload_mode == "zero_copy":
             if _sp_mode(cfg, n_slots, max_len):
@@ -762,6 +762,14 @@ class ServingEngine:
                 self.metrics["table_rows_uploaded"] += len(rows)
                 self.metrics["table_upload_bytes"] += int(sub.nbytes)
 
+    def _count_kv_pages(self, kv_len: np.ndarray) -> int:
+        """Count the KV pages that decoding rows with ``kv_len`` cached
+        tokens hold once this step's token is written, and return them."""
+        pages = int((-(-(kv_len.astype(np.int64) + 1) // self.page_size))
+                    .sum())
+        self.metrics["decode_kv_pages"] += pages
+        return pages
+
     def _decode_zero_copy(self, params, tokens, kv_len, tables, cache):
         cache = _install_tables(cache, tables, kv_len)
         return forward_decode(self.cfg, params, tokens, kv_len, cache, self.mi)
@@ -778,6 +786,8 @@ class ServingEngine:
             st = self.mgr.seqs[rid]
             last[st.slot, 0] = st.tokens[-1] if st.tokens else \
                 (req.prompt[-1] if req.prompt else 0)
+        self._count_kv_pages(kv_len[[self.mgr.seqs[r].slot
+                                     for r in self.active]])
         pos = jnp.asarray(kv_len)                       # write/rope position
         if self.offload_mode == "zero_copy":
             self._apply_cow()       # duplicated pages must exist before the
@@ -964,12 +974,13 @@ class ServingEngine:
         if not out.decode_slots:
             return
         rows = len(out.decode_slots)
-        with TraceAnnotation("engine.decode.pack", rows=rows):
+        with TraceAnnotation("engine.decode.pack", rows=rows) as span:
             lengths = self.mgr.device_lengths()
             mask = np.zeros((self.n_slots,), bool)
             mask[out.decode_slots] = True
             kv_len = np.where(mask, np.maximum(lengths - 1, 0), 0) \
                 .astype(np.int32)
+            span.set_metadata(pages=self._count_kv_pages(kv_len[mask]))
             last = np.where(mask, self.buffer.last_tokens(), 0) \
                 .astype(np.int32)[:, None]
             args = (jnp.asarray(last), jnp.asarray(kv_len), self._tables_dev,

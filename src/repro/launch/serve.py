@@ -36,7 +36,6 @@ def build_config(arch: str, *, smoke: bool = False,
 def build_engine(cfg: ModelConfig, params, *, slots: int, max_len: int,
                  page_size: int, offload_mode: str = "zero_copy",
                  pool_pages: Optional[int] = None,
-                 decode_backend: Optional[str] = None,
                  translation_stats: bool = False) -> ServingEngine:
     """The served engine: the continuous scheduler over the global
     zero-copy pool. The staging-copy baseline exists only on the fixed-wave
@@ -45,7 +44,6 @@ def build_engine(cfg: ModelConfig, params, *, slots: int, max_len: int,
     return ServingEngine(cfg, params, n_slots=slots, max_len=max_len,
                          page_size=page_size, offload_mode=offload_mode,
                          scheduler=scheduler, pool_pages=pool_pages,
-                         decode_backend=decode_backend,
                          translation_stats=translation_stats)
 
 

@@ -1,15 +1,17 @@
 """Attention: GQA projections, chunked flash attention, paged-KV decode.
 
-Three execution paths, all pure JAX (Pallas kernels in repro.kernels are the
-TPU-target equivalents, selected via ``cfg.use_pallas`` on real hardware):
+Three execution paths in pure JAX (Pallas kernels in repro.kernels are the
+TPU-target equivalents: the decode kernel serves on a TPU, the others are
+selected via ``cfg.use_pallas``):
 
   * ``flash_attention`` — memory-efficient chunked online-softmax attention
     (train / prefill). Scans over KV chunks carrying (m, l, acc).
   * ``paged_decode_attention`` — single-token decode over a *paged* KV pool
-    with block-table indirection: the paper's technique. The gather through
-    the block table is the IOVA translation; in the Pallas kernel
-    (kernels/paged_attention) the table is scalar-prefetched to SMEM, the
-    analogue of the paper's PTW-in-LLC.
+    with block-table indirection: the paper's technique. On a TPU the
+    ragged Pallas kernel (kernels/paged_attention) reads the shared pool
+    through the block table scalar-prefetched to SMEM, the analogue of the
+    paper's PTW-in-LLC; elsewhere the gather through the table is the IOVA
+    translation.
   * ``sp_decode_attention`` — sequence-parallel decode (long_500k): KV pages
     sharded over the data axis, flash-decoding-style (m, l, acc) merge via
     psum — page placement is sequence-affine (shard i owns logical pages
@@ -25,6 +27,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from repro.kernels import resolve_interpret
+from repro.kernels.paged_attention.kernel import ragged_paged_decode
 from repro.models.params import ParamSpec
 
 
@@ -367,18 +371,25 @@ def gather_pages(pool: jax.Array, table: jax.Array) -> jax.Array:
     return g.reshape(B, n_pages * page, H, D)
 
 
+def ragged_decode_serves(kv: PagedKV) -> bool:
+    """The one choice of decode-attention path: the ragged Pallas kernel
+    (``kernels/paged_attention.ragged_paged_decode``) serves a global-layout
+    pool wherever it runs compiled, i.e. on a TPU
+    (``repro.kernels.resolve_interpret``). Elsewhere, and for the per-slot
+    layout, the pure-JAX paths below serve."""
+    return is_global_layout(kv) and not resolve_interpret()
+
+
 def paged_decode_attention(q: jax.Array, kv: PagedKV, *,
-                           softcap: Optional[float] = None,
-                           backend: str = "jax") -> jax.Array:
+                           softcap: Optional[float] = None) -> jax.Array:
     """One-token decode over the paged pool. q: (B, 1, Hq, D).
 
-    ``backend="pallas"`` routes the step through the scalar-prefetch Pallas
-    kernel (kernels/paged_attention): the block table lives in SMEM and
-    drives the KV page DMAs directly — the paper's PTW-in-LLC realized on
-    the serving hot path (compiled on a TPU, interpret mode elsewhere; see
-    ``repro.kernels.resolve_interpret``). Both PagedKV layouts are supported;
-    a table with a leading stacked-blocks axis (outside the layer scan) is
-    refused rather than served by the pure-JAX path.
+    On a global-layout pool, where ``ragged_decode_serves`` says so, the
+    ragged Pallas kernel reads each row's live pages out of the pool where
+    they lie: the block table lives in SMEM and drives the KV page DMAs —
+    the paper's PTW-in-LLC realized on the serving hot path. A layer's view
+    of the stacked pool is read through its layer index, so no layer slice
+    is taken; NULL entries and masked rows cost nothing.
 
     Sliding-window layers use a pool whose capacity equals the window; the
     rolling write in ``paged_append`` makes every slot valid once
@@ -397,27 +408,15 @@ def paged_decode_attention(q: jax.Array, kv: PagedKV, *,
     block table (B x n_pages ints) — instead of gathering the pool data
     itself. This removes a full pool copy per layer per step (the paper's
     map-don't-copy insight applied to the kernel's own data movement).
-    This holds for the per-slot layout. The global layout's whole-pool
-    copies were in the layer scan, which sliced each layer's pool out of
-    the stack and restacked it; that pool now rides the scan carry
-    (``PagedKV.layer``).
+    This holds for the per-slot layout. The global layout's pure-JAX path
+    gathers every table entry; the kernel reads only the live pages.
     """
     B, _, Hq, D = q.shape
-    if backend == "pallas":
-        if kv.block_table.ndim != 2:
-            raise ValueError(
-                "decode_backend='pallas' serves one layer's (B, P) block "
-                f"table; got a table of shape {kv.block_table.shape} (a "
-                "leading stacked-blocks axis belongs inside the layer scan)")
-        from repro.kernels.paged_attention.ops import paged_decode
-        lengths = jnp.broadcast_to(kv.length, (B,)).astype(jnp.int32)
-        k_pool, v_pool = kv.k_pool, kv.v_pool
-        if kv.layer is not None:        # the kernel takes one layer's pool
-            k_pool, v_pool = k_pool[kv.layer], v_pool[kv.layer]
-        out = paged_decode(q[:, 0], k_pool, v_pool,
-                           kv.block_table.astype(jnp.int32), lengths,
-                           softcap=softcap)
-        return out[:, None].astype(q.dtype)
+    if ragged_decode_serves(kv):
+        out = ragged_paged_decode(q[:, 0], kv.k_pool, kv.v_pool,
+                                  kv.block_table, kv.length, kv.layer,
+                                  softcap=softcap)
+        return out[:, None]
     if is_global_layout(kv):
         # GLOBAL POOL: each sequence sees its pages in LOGICAL order through
         # its table row — the gather IS the IOVA translation. NULL entries

@@ -211,8 +211,7 @@ def _self_attention(p, x, ctx: FwdCtx, cache, window):
                 kv_new = attn.paged_append(kv, k, v)
             with jax.named_scope("attention"):
                 o = attn.paged_decode_attention(q, kv_new,
-                                                softcap=cfg.attn_softcap,
-                                                backend=cfg.decode_backend)
+                                                softcap=cfg.attn_softcap)
         return attn.out_proj(p, o), {**cache, "kv": kv_new}
     if (ctx.kv_prefix_lens is not None and ctx.mode == "prefill"
             and cache is not None and "kv" in cache

@@ -13,6 +13,7 @@ import chip_smoke
 from repro.kernels import resolve_interpret
 from repro.launch import compile_cache
 from repro.launch.serve import build_config, build_engine
+from repro.models import attention as attn
 from repro.models import init_params
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
@@ -24,13 +25,15 @@ def smoke():
     return cfg, init_params(cfg, jax.random.key(0))
 
 
-def test_chip_smoke_phases_at_smoke_size(smoke):
-    """Serve through the continuous engine with the Pallas decode backend
-    (interpret mode here) over a pool larger than the slots' worst case,
-    then check the kernel on the live pool against the float32 reference."""
+def test_chip_smoke_phases_at_smoke_size(smoke, monkeypatch):
+    """Serve through the continuous engine with the ragged decode kernel
+    (interpret mode here, forced through the one choice) over a pool
+    larger than the slots' worst case, then check the kernel on the live
+    pool against the float32 reference."""
     cfg, params = smoke
+    monkeypatch.setattr(attn, "ragged_decode_serves", attn.is_global_layout)
     eng = build_engine(cfg, params, slots=4, max_len=64, page_size=8,
-                       pool_pages=40, decode_backend="pallas")
+                       pool_pages=40)
     assert eng.scheduler_mode == "continuous"
     assert eng.null_page == 40
     prompts = chip_smoke.make_prompts(4, cfg.vocab_size, (8, 40), seed=0)
@@ -46,16 +49,20 @@ def test_chip_smoke_phases_at_smoke_size(smoke):
     assert kc["jax_err"] <= chip_smoke.KERNEL_TOL * (1 + kc["scale"])
 
 
-def test_chip_smoke_backends_agree_at_smoke_size(smoke):
-    """At float32 smoke widths the Pallas and pure-JAX decode backends
-    serve the same tokens through chip_smoke's serve phase."""
+def test_chip_smoke_backends_agree_at_smoke_size(smoke, monkeypatch):
+    """At float32 smoke widths the ragged kernel and the pure-JAX decode
+    path serve the same tokens through chip_smoke's serve phase, each
+    chosen the way chip_smoke chooses it."""
     cfg, params = smoke
     prompts = chip_smoke.make_prompts(3, cfg.vocab_size, (8, 30), seed=1)
     outs = []
-    for backend in ("jax", "pallas"):
-        eng = build_engine(cfg, params, slots=3, max_len=64, page_size=8,
-                           decode_backend=backend)
+    with chip_smoke.jax_decode_path():
+        eng = build_engine(cfg, params, slots=3, max_len=64, page_size=8)
         outs.append(chip_smoke.serve(eng, prompts, 4)[0])
+    # off a TPU the kernel path is forced, and interpreted
+    monkeypatch.setattr(attn, "ragged_decode_serves", attn.is_global_layout)
+    eng = build_engine(cfg, params, slots=3, max_len=64, page_size=8)
+    outs.append(chip_smoke.serve(eng, prompts, 4)[0])
     assert outs[0] == outs[1]
 
 
@@ -89,17 +96,16 @@ def test_compile_cache_defaults_inside_checkout(monkeypatch):
     assert ".jax_cache/" in (ROOT / ".gitignore").read_text().split()
 
 
-def test_pallas_backend_refuses_stacked_table():
-    """backend="pallas" never takes the pure-JAX path in silence: a table
+def test_pallas_backend_refuses_stacked_table(monkeypatch):
+    """The ragged kernel never takes the pure-JAX path in silence: a table
     with a leading stacked-blocks axis raises."""
     import jax.numpy as jnp
-    from repro.models.attention import PagedKV, paged_decode_attention
+    monkeypatch.setattr(attn, "ragged_decode_serves", attn.is_global_layout)
     pool = jnp.zeros((2, 6, 8, 2, 16))               # (blocks, pages, ...)
-    kv = PagedKV(pool, pool, jnp.zeros((2, 3, 4), jnp.int32),
-                 jnp.zeros((2, 3), jnp.int32))
-    with pytest.raises(ValueError, match="pallas"):
-        paged_decode_attention(jnp.zeros((3, 1, 4, 16)), kv,
-                               backend="pallas")
+    kv = attn.PagedKV(pool, pool, jnp.zeros((2, 3, 4), jnp.int32),
+                      jnp.zeros((2, 3), jnp.int32))
+    with pytest.raises(ValueError, match="ragged paged decode"):
+        attn.paged_decode_attention(jnp.zeros((3, 1, 4, 16)), kv)
 
 
 @pytest.mark.parametrize("platform,interpret", [

@@ -9,6 +9,7 @@ steps that carry chunks. The jitted programs keep the XLA module names by
 which the benchmark finds them in a device trace, and their operations the
 model's scopes."""
 import dataclasses
+import functools
 import re
 
 import jax
@@ -109,6 +110,39 @@ def test_prefill_token_counters(traced):
     packs = [e for e in tr.engine if e.name == "engine.prefill.pack"]
     assert [(e.arg("rows"), e.arg("tokens"), e.arg("real")) for e in packs] \
         == [(2, 128, 101), (1, 64, 64), (1, 64, 36)]
+
+
+def test_decode_kv_pages(engine, traced):
+    """The pages the decoding rows hold once the step's token is written,
+    which the decode kernel reads: on the decode pack span as ``pages`` and
+    summed in ``stats()["decode_kv_pages"]``. Rows of 64 and 37 prompt
+    tokens decode from step 1 (65 and 38 tokens: 9 + 5 pages of 8), the
+    100-token prompt from step 3 (101 tokens: 13 pages)."""
+    tr, _, _ = traced
+    pages = [e.arg("pages") for e in tr.engine
+             if e.name == "engine.decode.pack"]
+    assert pages == [9 + 5, 9 + 5, 9 + 5 + 13, 9 + 6 + 13]
+    assert engine.stats()["decode_kv_pages"] == sum(pages)
+
+
+def test_decode_kernel_keeps_the_attention_scope(engine, monkeypatch):
+    """Lowered for a TPU, the decode program reads the pool through the
+    ragged kernel, whose custom call sits under the ``attention`` scope that
+    ``decode.attn_device_ms_per_step`` reads."""
+    eng = engine
+    S = eng.n_slots
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    # a program of its own: the engine's was traced for the CPU
+    decode = jax.jit(functools.partial(ServingEngine._decode_masked, eng))
+    text = decode.trace(
+        eng.params, jnp.zeros((S, 1), jnp.int32), jnp.zeros((S,), jnp.int32),
+        eng._tables_dev, jnp.zeros((S,), bool), eng.cache,
+    ).lower(lowering_platforms=("tpu",)).as_text(debug_info=True)
+    calls = re.findall(r"custom_call @tpu_custom_call\(.*loc\((#loc\d+)\)$",
+                       text, re.M)
+    assert len(calls) == 1
+    name, = re.findall(rf'^{calls[0]} = loc\("([^"]*)"', text, re.M)
+    assert "/attention/" in name
 
 
 def test_removed_counters_are_gone(engine):

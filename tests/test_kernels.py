@@ -96,28 +96,64 @@ def test_paged_attention(B, Hq, Hkv, n_pages, page, cap, residency, key):
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
 
 
-@pytest.mark.parametrize("residency", ["smem", "hbm"])
-@pytest.mark.parametrize("cap", [None, 30.0])
-def test_paged_attention_global_layout(residency, cap, key):
-    """Shared-global-pool kernel: slots may map the SAME physical page (CoW
-    prefix sharing) and unallocated entries hold the NULL sentinel."""
-    from repro.kernels.paged_attention.kernel import paged_attention_global
+@pytest.mark.parametrize("G,Hkv,dtype,stacked,cap", [
+    (1, 2, jnp.float32, True, None),
+    (7, 2, jnp.float32, True, 30.0),
+    (1, 2, jnp.float32, False, None),
+    (7, 2, jnp.float32, False, None),
+    (7, 4, jnp.bfloat16, True, None),       # two heads to a 32-bit word
+    (1, 4, jnp.bfloat16, False, 30.0),
+    (7, 1, jnp.bfloat16, True, None),
+])
+def test_paged_attention_global_layout(G, Hkv, dtype, stacked, cap, key,
+                                       monkeypatch):
+    """The ragged kernel over the shared global pool, against the float32
+    oracle. Rows of length 0, 1, page - 1, page, page + 1 and the full
+    table; two rows map the SAME physical pages (CoW prefix sharing); a
+    masked row (length 1 through an all-NULL table, as the engine's masked
+    decode leaves it); entries past a row's pages hold NULL or a page of
+    NaNs, which the kernel must never read. Blocks of 3 pages do not divide
+    the 8-page table. A stacked pool is read at a layer index."""
+    from repro.kernels.paged_attention import kernel as K
     from repro.kernels.paged_attention.ref import paged_attention_global_ref
-    B, Hq, Hkv, total, P, page, D = 3, 8, 2, 12, 4, 16, 64
+    page, P, D, L, layer = 4, 8, 64, 3, 1
+    Hq = G * Hkv
+    lens = [0, 1, page - 1, page, page + 1, P * page, 2 * page + 3,
+            2 * page + 1, 1]
+    B = len(lens)
+    monkeypatch.setattr(K, "_BLOCK_BYTES",
+                        3 * page * Hkv * D * jnp.dtype(dtype).itemsize)
+    total = sum(-(-n // page) for n in lens) + 1       # + one NaN page
+    nan_page = total - 1
+    tbl = np.full((B, P), total, np.int32)             # NULL
+    nxt = 0
+    for b, n in enumerate(lens[:-1]):
+        k = -(-n // page)
+        tbl[b, :k] = np.arange(nxt, nxt + k)
+        nxt += k
+    tbl[7, :2] = tbl[6, :2]            # row 7 shares row 6's first pages
+    tbl[2, 1:] = nan_page              # past its pages: a page of NaNs
+    tbl[4, 2] = nan_page
     ks = jax.random.split(key, 3)
     q = jax.random.normal(ks[0], (B, Hq, D))
-    kp = jax.random.normal(ks[1], (total, page, Hkv, D))
-    vp = jax.random.normal(ks[2], (total, page, Hkv, D))
-    tbl = jnp.asarray([[0, 1, 2, total],       # slot 0
-                       [0, 1, 5, total],       # slot 1 SHARES pages 0, 1
-                       [total] * 4],           # empty slot: all NULL
-                      jnp.int32)
-    lens = jnp.asarray([3 * page - 5, 2 * page + 3, 0], jnp.int32)
-    out = paged_attention_global(q, kp, vp, tbl, lens, softcap=cap,
-                                 table_residency=residency)
-    ref = paged_attention_global_ref(q, kp, vp, tbl, lens, softcap=cap)
-    np.testing.assert_allclose(np.asarray(out[:2]), np.asarray(ref[:2]),
-                               atol=1e-5)
+    kp = jax.random.normal(ks[1], (L, total, page, Hkv, D)).astype(dtype)
+    vp = jax.random.normal(ks[2], (L, total, page, Hkv, D)).astype(dtype)
+    kp = kp.at[:, nan_page].set(jnp.nan)
+    vp = vp.at[:, nan_page].set(jnp.nan)
+    tbl, lengths = jnp.asarray(tbl), jnp.asarray(lens, jnp.int32)
+    if stacked:
+        out = K.ragged_paged_decode(q, kp, vp, tbl, lengths, jnp.int32(layer),
+                                    softcap=cap)
+    else:
+        out = K.ragged_paged_decode(q, kp[layer], vp[layer], tbl, lengths,
+                                    softcap=cap)
+    clean = lambda pool: jnp.nan_to_num(pool[layer].astype(jnp.float32))
+    ref = paged_attention_global_ref(q, clean(kp), clean(vp), tbl, lengths,
+                                     softcap=cap)
+    live = np.asarray([n > 0 for n in lens[:-1]] + [False])
+    np.testing.assert_allclose(np.asarray(out)[live], np.asarray(ref)[live],
+                               atol=1e-5, rtol=1e-5)
+    assert np.all(np.asarray(out)[~live] == 0)    # no KV: zeros, no reads
 
 
 @pytest.mark.parametrize("S,Hq,Hkv,causal", [

@@ -400,21 +400,28 @@ def test_prefix_cache_lru_eviction_under_pressure():
     mgr.pool.check_invariants()
 
 
-def test_engine_pallas_decode_backend_matches_jax(key):
-    """The Pallas paged-decode kernel on the hot path (interpret mode on
-    CPU) produces the same tokens as the pure-JAX gather path."""
+def test_engine_pallas_decode_backend_matches_jax(key, monkeypatch):
+    """The ragged paged-decode kernel on the hot path (interpret mode on
+    CPU, forced through ``attention.ragged_decode_serves``) produces the
+    same tokens as the pure-JAX gather path, on the fixed-wave and the
+    continuous engine."""
+    from repro.models import attention as attn
     cfg = reduce_for_smoke(get_config("llama3.2-1b"))
     params = init_params(cfg, key)
-    prompts = [SYS + [5, 9, 2], [11, 4]]
+    prompts = [SYS + [5, 9, 2], [11, 4], SYS + [7]]
+    serves = attn.ragged_decode_serves
 
-    def run(backend):
+    def run(kernel, scheduler):
+        monkeypatch.setattr(attn, "ragged_decode_serves",
+                            attn.is_global_layout if kernel else serves)
         eng = ServingEngine(cfg, params, n_slots=2, max_len=32, page_size=8,
-                            decode_backend=backend)
+                            scheduler=scheduler)
         rids = [eng.submit(p, max_tokens=4) for p in prompts]
         done = eng.run()
         return [done[r].out_tokens for r in rids]
 
-    assert run("pallas") == run("jax")
+    for scheduler in ("fixed", "continuous"):
+        assert run(True, scheduler) == run(False, scheduler)
 
 
 def _prefix_policy_scenario(policy):
