@@ -93,7 +93,7 @@ def run(dry_run: bool = False) -> List[str]:
 
     # Colocated reference: 4 slots, every slot admits AND decodes.
     ref_eng = ServingEngine(cfg, params, n_slots=4, max_len=64, page_size=8,
-                            scheduler="continuous", pool_pages=_BURST_POOL)
+                            pool_pages=_BURST_POOL)
     ref_outs, ref_reqs, _ = _drive(ref_eng, prompts, maxtoks, arrivals)
 
     rows = []

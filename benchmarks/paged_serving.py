@@ -115,12 +115,11 @@ def _run_prefix_workload(share: bool, n_req: int, max_tokens: int,
     return wall, eng.stats(), outs
 
 
-# -------------------------------------------- bursty scheduler A/B workload
+# ------------------------------------------ bursty continuous workload
 
 # Mixed-length bursty mix tuned so the shared pool is oversubscribed: the
-# fixed scheduler must WAIT at admission while continuous lazily over-admits
-# and preempts under pressure — the regime where token-budget scheduling
-# wins (vLLM/eSurge).
+# continuous loop lazily over-admits and preempts under pressure, the
+# regime token-budget scheduling exists for (vLLM/eSurge).
 _BURST_LENS = (11, 23, 5, 17, 9, 13)
 _BURST_MAXTOKS = (10, 8, 12, 9, 11, 10)
 _BURST_POOL = 8          # pages; n_slots*max_pages would be 32 (no pressure)
@@ -140,14 +139,14 @@ def _bursty_workload(vocab: int, n_req: int):
     return prompts, list(_BURST_MAXTOKS[:n_req]), arrivals
 
 
-def _run_bursty(scheduler: str, pool_pages, prompts, maxtoks, arrivals):
+def _run_bursty(prompts, maxtoks, arrivals, **engine_kw):
     """Drive one engine over the bursty arrival schedule, injecting each
     submission between steps at its arrival tick (the engine never sees
     the future). Returns (stats, per-request outputs, steps-to-first-token
     per request, engine steps executed)."""
     cfg, params = _cfg_params()
     eng = ServingEngine(cfg, params, n_slots=4, max_len=64, page_size=8,
-                        scheduler=scheduler, pool_pages=pool_pages)
+                        **engine_kw)
     finished = {}
     rids = [None] * len(prompts)
     order = sorted(range(len(prompts)), key=lambda j: arrivals[j])
@@ -167,42 +166,28 @@ def _run_bursty(scheduler: str, pool_pages, prompts, maxtoks, arrivals):
     return eng.stats(), outs, ttft, n_steps
 
 
-def run_scheduler_ab(dry_run: bool = False) -> List[str]:
-    """Fixed vs continuous scheduling on the SAME bursty workload at the
-    SAME (oversubscribed) page pool: tokens/step, steps-to-first-token,
-    preemption counts — with both constrained runs checked bit-identical
-    to an unconstrained reference (scheduling policy never changes
-    tokens)."""
+def run_scheduler_rows(dry_run: bool = False) -> List[str]:
+    """The continuous loop on the bursty workload over an oversubscribed
+    pool: tokens/step, steps-to-first-token and preemption counts, with
+    the outputs checked bit-identical to the copy-mode reference (whole
+    prompts prefilled into per-slot pools, no pool pressure). Scheduling
+    policy never changes tokens."""
     n_req = 4 if dry_run else 6
     vocab = reduce_for_smoke(get_config("llama3.2-1b")).vocab_size
     prompts, maxtoks, arrivals = _bursty_workload(vocab, n_req)
-    _, ref_outs, _, _ = _run_bursty("fixed", None, prompts, maxtoks,
-                                    arrivals)
-    rows, tps = [], {}
-    identical = True
-    for sched in ("fixed", "continuous"):
-        s, outs, ttft, n_steps = _run_bursty(sched, _BURST_POOL, prompts,
-                                             maxtoks, arrivals)
-        identical = identical and outs == ref_outs
-        tps[sched] = s["tokens"] / max(n_steps, 1)
-        extra = ""
-        if sched == "continuous":
-            extra = (f" preemptions={s['preemptions']}"
-                     f" resumes={s['resumes']}")
-        rows.append(
-            f"paged_serving.sched.{sched}.tokens_per_step,"
-            f"{tps[sched]:.2f},{s['tokens']} decode tokens in {n_steps} "
-            f"steps at pool_pages={_BURST_POOL} "
-            f"mean_steps_to_first_token={np.mean(ttft):.1f}{extra}")
-    rows.append(f"paged_serving.sched.continuous_advantage,"
-                f"{100 * (tps['continuous'] / max(tps['fixed'], 1e-9) - 1):.0f},"
-                "percent higher tokens/step from token-budget scheduling "
-                "under pool pressure (equal pool, equal workload)")
-    rows.append(f"paged_serving.sched.bit_identical,{identical},"
-                "pool-constrained fixed AND continuous outputs vs the "
-                "unconstrained reference (chunked prefill + preempt/resume "
-                "never change tokens)")
-    return rows
+    _, ref_outs, _, _ = _run_bursty(prompts, maxtoks, arrivals,
+                                    offload_mode="copy")
+    s, outs, ttft, n_steps = _run_bursty(prompts, maxtoks, arrivals,
+                                         pool_pages=_BURST_POOL)
+    return [
+        f"paged_serving.sched.continuous.tokens_per_step,"
+        f"{s['tokens'] / max(n_steps, 1):.2f},{s['tokens']} decode tokens "
+        f"in {n_steps} steps at pool_pages={_BURST_POOL} "
+        f"mean_steps_to_first_token={np.mean(ttft):.1f} "
+        f"preemptions={s['preemptions']} resumes={s['resumes']}",
+        f"paged_serving.sched.bit_identical,{outs == ref_outs},"
+        "pool-constrained continuous outputs vs the copy-mode reference "
+        "(chunked prefill + preempt/resume never change tokens)"]
 
 
 def run_range_ab(dry_run: bool = False, tlb_ranges: int = 8) -> List[str]:
@@ -217,8 +202,7 @@ def run_range_ab(dry_run: bool = False, tlb_ranges: int = 8) -> List[str]:
         cfg, params = _cfg_params()
         cfg = dataclasses.replace(cfg, serve_tlb_ranges=ranges)
         eng = ServingEngine(cfg, params, n_slots=4, max_len=64, page_size=8,
-                            scheduler="continuous", pool_pages=_BURST_POOL,
-                            translation_stats=True)
+                            pool_pages=_BURST_POOL, translation_stats=True)
         prompts = _prefix_heavy_prompts(n_req, cfg.vocab_size)
         rids = [eng.submit(p, max_tokens=max_tokens) for p in prompts]
         done = eng.run()
@@ -256,8 +240,8 @@ def _run_tenant_scenario(reqs, dep, pool_pages: int):
                                   serve_tlb_ways=_TENANT_TLB_WAYS)
         tenants = None
     eng = ServingEngine(cfg, params, n_slots=4, max_len=64, page_size=8,
-                        scheduler="continuous", pool_pages=pool_pages,
-                        translation_stats=True, tenants=tenants)
+                        pool_pages=pool_pages, translation_stats=True,
+                        tenants=tenants)
     finished = {}
     rids = []
     i, clock = 0, 0
@@ -461,8 +445,8 @@ def run(dry_run: bool = False, tlb_ranges: int = 8) -> List[str]:
                 f"{kv_bytes/max(table_bytes,1):.0f},x less traffic with "
                 "SMEM-resident tables (qwen2-7b decode_32k)")
 
-    # ------------------------------ scheduler A/B on the bursty workload
-    rows += run_scheduler_ab(dry_run)
+    # --------------------------- continuous loop on the bursty workload
+    rows += run_scheduler_rows(dry_run)
     # ------------------- range-coalesced IOTLB on/off bit-identity check
     if tlb_ranges:
         rows += run_range_ab(dry_run, tlb_ranges=tlb_ranges)
@@ -529,7 +513,6 @@ def run_translation_report(dry_run: bool = False,
                            prefetch_degree: int = 2,
                            prefetch_distance: int = 4,
                            autotune: int = 0,
-                           scheduler: str = "fixed",
                            tlb_ranges: int = 8) -> List[str]:
     """Fig. 5 on the serving hot path: serve a prefix-heavy workload with
     translation tracing, then price the recorded per-decode-step page
@@ -542,9 +525,8 @@ def run_translation_report(dry_run: bool = False,
     live-TLB row reflects them end-to-end; the default leaves every knob
     off and the pre-existing report rows bit-identical.
 
-    ``scheduler="continuous"`` serves the SAME workload through the
-    continuous-batching scheduler over an oversubscribed page pool, so
-    the recorded trace bears ``("preempt", ...)`` / ``("resume", ...)``
+    The workload is served over an oversubscribed page pool, so the
+    recorded trace bears ``("preempt", ...)`` / ``("resume", ...)``
     annotations around real ASID teardown/re-mapping — exercising the
     replay path on preemption-bearing traces."""
     n_req, max_tokens = (4, 4) if dry_run else (10, 10)
@@ -554,10 +536,9 @@ def run_translation_report(dry_run: bool = False,
         serve_tlb_prefetch_degree=prefetch_degree,
         serve_tlb_prefetch_distance=prefetch_distance,
         serve_tlb_autotune=autotune)
-    pool = _BURST_POOL if scheduler == "continuous" else None
     eng = ServingEngine(cfg, params, n_slots=4, max_len=64, page_size=8,
                         record_translation_trace=True,
-                        scheduler=scheduler, pool_pages=pool)
+                        pool_pages=_BURST_POOL)
     for p in _prefix_heavy_prompts(n_req, cfg.vocab_size):
         eng.submit(p, max_tokens=max_tokens)
     eng.run()
@@ -573,13 +554,12 @@ def run_translation_report(dry_run: bool = False,
     rows = [f"translation.trace.steps,{n_steps},"
             f"decode steps recorded ({len(trace)} events; "
             f"kv_bytes_per_token={kv_tok})"]
-    if scheduler == "continuous":
-        n_pre = sum(1 for ev in trace if ev[0] == "preempt")
-        n_res = sum(1 for ev in trace if ev[0] == "resume")
-        rows.append(f"translation.trace.preemptions,{n_pre},"
-                    f"preempt/resume annotations in the continuous trace "
-                    f"(resumes={n_res}; pool_pages={pool}) — replayed "
-                    f"through every design point below")
+    n_pre = sum(1 for ev in trace if ev[0] == "preempt")
+    n_res = sum(1 for ev in trace if ev[0] == "resume")
+    rows.append(f"translation.trace.preemptions,{n_pre},"
+                f"preempt/resume annotations in the trace "
+                f"(resumes={n_res}; pool_pages={_BURST_POOL}) — replayed "
+                f"through every design point below")
     live = eng.stats()["tlb"]
     rows.append(f"translation.live_tlb_hit_rate,{live['hit_rate']},"
                 f"serving IOMMU (4096-entry CountingWalk) on live traffic: "
@@ -792,13 +772,6 @@ if __name__ == "__main__":
                     help="auto-tune the served engine's TLB geometry with "
                          "this measurement window in decode steps "
                          "(ModelConfig.serve_tlb_autotune; 0 = off)")
-    ap.add_argument("--scheduler", default="fixed",
-                    choices=("fixed", "continuous"),
-                    help="scheduler for the --translation-report serving "
-                         "run; 'continuous' serves over an oversubscribed "
-                         "pool so the recorded trace bears preempt/resume "
-                         "events (the default benchmark always runs the "
-                         "fixed-vs-continuous A/B)")
     ap.add_argument("--tlb-ranges", type=int, default=8,
                     help="max pages per range-coalesced IOTLB entry (>= 2) "
                          "for the range on/off serving A/B and the "
@@ -814,8 +787,7 @@ if __name__ == "__main__":
             prefetch_policy=args.prefetch,
             prefetch_degree=args.prefetch_degree,
             prefetch_distance=args.prefetch_distance,
-            autotune=args.autotune, scheduler=args.scheduler,
-            tlb_ranges=args.tlb_ranges)))
+            autotune=args.autotune, tlb_ranges=args.tlb_ranges)))
     else:
         print("\n".join(run(dry_run=args.dry_run,
                             tlb_ranges=args.tlb_ranges)))

@@ -2,11 +2,11 @@
 static grid + adaptive front-end rows.
 
 Records real serving translation traces (``ServingEngine(
-record_translation_trace=True)``) for three deployment profiles — a
-prefix-heavy mix (shared system prompt, CoW divergence), an all-unique
-mix (no cross-request reuse), and a continuous-batching mix served over an
-oversubscribed page pool (its trace bears preempt/resume events around
-real ASID teardown/re-mapping) — then replays each trace through the
+record_translation_trace=True)``, all on the engine's continuous loop) for
+three deployment profiles — a prefix-heavy mix (shared system prompt, CoW
+divergence), an all-unique mix (no cross-request reuse), and the
+prefix-heavy mix again over an oversubscribed page pool (its trace bears
+preempt/resume events around real ASID teardown/re-mapping) — then replays each trace through the
 unified IOMMU front-end across a grid of hardware geometries:
 
   IOTLB entries x set associativity (ways) x replacement policy
@@ -118,9 +118,9 @@ def sweep_grid(smoke: bool = False) -> List[Geometry]:
 
 def record_traces(dry_run: bool = False) -> Tuple[Dict[str, list], dict]:
     """Serve three deployment profiles with translation tracing ON. Returns
-    ({deployment: trace}, cost model constants for the replay). The
-    ``continuous`` profile serves through the continuous-batching scheduler
-    over an oversubscribed page pool, so its trace bears
+    ({deployment: trace}, cost model constants for the replay). All three
+    are recorded on the engine's continuous loop. The ``continuous``
+    profile serves over an oversubscribed page pool, so its trace bears
     ``("preempt", ...)`` / ``("resume", ...)`` annotations around real ASID
     teardown/re-mapping — the replay path is exercised on preemption-bearing
     traces even at ``--smoke`` scale."""
@@ -149,7 +149,7 @@ def record_traces(dry_run: bool = False) -> Tuple[Dict[str, list], dict]:
               for _ in range(n_req)]
     _, unique_trace = serve(unique)
     _, cont_trace = serve(_prefix_heavy_prompts(n_req, cfg.vocab_size),
-                          scheduler="continuous", pool_pages=_BURST_POOL)
+                          pool_pages=_BURST_POOL)
 
     n_attn = sum(1 for k in cfg.layer_kinds() if "attn" in k)
     consts = dict(

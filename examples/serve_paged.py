@@ -1,6 +1,6 @@
-"""Paged serving example: continuous batching with zero-copy admission,
-copy-on-write prefix sharing, SVA/TLB statistics, and the adaptive
-translation front-end (IOTLB prefetching + online TLB-geometry
+"""Paged serving example: the continuous-batching engine with zero-copy
+admission, copy-on-write prefix sharing, SVA/TLB statistics, and the
+adaptive translation front-end (IOTLB prefetching + online TLB-geometry
 auto-tuning).
 
 Most requests open with the same system prompt, so admission maps the
@@ -11,15 +11,13 @@ CoW-duplicate it on their first divergent token.
   PYTHONPATH=src python examples/serve_paged.py
   PYTHONPATH=src python examples/serve_paged.py --tlb-prefetch stream \
       --tlb-autotune 4
-  PYTHONPATH=src python examples/serve_paged.py --scheduler continuous
 
-``--scheduler continuous`` switches to the token-budget continuous-batching
-scheduler and serves the same requests as two bursty arrival waves over an
-OVERSUBSCRIBED page pool (``--pool-pages``): admission is lazy (prompt
-pages only), prompts prefill in chunks inside mixed decode steps, and pool
-pressure is resolved by preempting the newest sequence (its KV goes warm
-into the prefix cache; resume re-matches it) — watch the preemptions /
-resumes / steps-to-first-token lines.
+The requests arrive as two bursty waves over an OVERSUBSCRIBED page pool
+(``--pool-pages``, 16 by default): admission is lazy (prompt pages only),
+prompts prefill in chunks inside mixed decode steps, and pool pressure is
+resolved by preempting the newest sequence (its KV goes warm into the
+prefix cache; resume re-matches it) — watch the preemptions / resumes /
+steps-to-first-token lines.
 
 ``--disagg share`` (or ``copy``) splits the same demo across a prefill
 worker and a decode worker connected by IOMMU-priced KV transfers:
@@ -67,22 +65,16 @@ ap.add_argument("--tlb-ranges", type=int, default=0, metavar="N",
                      "physically contiguous run of up to N pages "
                      "(ModelConfig.serve_tlb_ranges; 0 = per-page, else "
                      ">= 2 — watch the range: block in the IOMMU stats)")
-ap.add_argument("--scheduler", default="fixed",
-                choices=("fixed", "continuous"),
-                help="continuous = token-budget scheduling with chunked "
-                     "prefill and preempt/resume, demoed as two bursty "
-                     "arrival waves over an oversubscribed pool")
 ap.add_argument("--disagg", default="off",
                 choices=("off", "copy", "share"),
                 help="disaggregate into a 2-slot prefill worker + 2-slot "
                      "decode worker; finished prefills hand their KV off "
                      "by IOMMU-priced migration (share = zero-copy page "
-                     "re-attachment, copy = staged payload). Implies the "
-                     "continuous two-wave demo")
-ap.add_argument("--pool-pages", type=int, default=0,
+                     "re-attachment, copy = staged payload)")
+ap.add_argument("--pool-pages", type=int, default=16,
                 help="physical KV page pool size (0 = full n_slots*pages "
-                     "reservation; --scheduler continuous defaults to an "
-                     "oversubscribed 16-page pool so preemption fires)")
+                     "reservation; the default oversubscribes the pool so "
+                     "preemption fires)")
 args = ap.parse_args()
 
 cfg = reduce_for_smoke(get_config("qwen2-7b"))
@@ -97,8 +89,7 @@ cfg = dataclasses.replace(
     # differentiate within a short example run.
     serve_tlb_entries=64 if args.tlb_autotune else cfg.serve_tlb_entries)
 params = init_params(cfg, jax.random.key(0))
-bursty = args.scheduler == "continuous" or args.disagg != "off"
-pool_pages = args.pool_pages or (16 if bursty else 0)
+pool_pages = args.pool_pages or None
 if args.disagg != "off":
     # Prefill/decode disaggregation at the same total width; the transfer
     # fabric prices each hand-off as the paper's 4-entry IOTLB over a
@@ -108,13 +99,10 @@ if args.disagg != "off":
                        disagg_mode=args.disagg,
                        xfer_iommu=IOMMU(walk_model=Sv39Walk(llc=False),
                                         tlb=TLBConfig(4, "lru")),
-                       pool_pages=pool_pages or None,
-                       translation_stats=True)
+                       pool_pages=pool_pages, translation_stats=True)
 else:
     eng = ServingEngine(cfg, params, n_slots=4, max_len=128, page_size=8,
-                        offload_mode="zero_copy",
-                        scheduler=args.scheduler,
-                        pool_pages=pool_pages or None,
+                        offload_mode="zero_copy", pool_pages=pool_pages,
                         translation_stats=True)  # live IOTLB hit/miss counts
 
 rng = np.random.default_rng(0)
@@ -125,26 +113,21 @@ prompts = [system + rng.integers(0, cfg.vocab_size,
 prompts.append(list(prompts[1]))                 # exact duplicate
 prompts += [rng.integers(0, cfg.vocab_size, size=12).tolist()
             for _ in range(2)]                   # unrelated
-if bursty:
-    workers = (f"a 2-slot prefill worker + 2-slot decode worker "
-               f"({args.disagg}-mode KV transfer) and " if args.disagg != "off"
-               else "")
-    print(f"two bursty arrival waves of 10 requests over {workers}an "
-          f"oversubscribed {eng.mgr.pool.n_pages}-page pool (lazy "
-          "admission, chunked prefill, preempt/resume under pressure)...")
-    finished = {}
-    # Longer generations than the fixed demo: decode growth (one page per
-    # 8 tokens per sequence) is what oversubscribes the pool.
-    rids = [eng.submit(p, max_tokens=24) for p in prompts[:6]]
-    for _ in range(3):                           # burst 2 lands mid-serve
-        eng.step(finished)
-    rids += [eng.submit(p, max_tokens=24) for p in prompts[6:]]
-    done = {**finished, **eng.run()}
-else:
-    print("submitting 10 requests into 4 slots (continuous batching; "
-          "8 share a system prompt, 2 are exact duplicates)...")
-    rids = [eng.submit(p, max_tokens=10) for p in prompts]
-    done = eng.run()
+workers = (f"a 2-slot prefill worker + 2-slot decode worker "
+           f"({args.disagg}-mode KV transfer) and " if args.disagg != "off"
+           else "4 slots and ")
+print(f"two bursty arrival waves of 10 requests (8 share a system prompt, "
+      f"2 are exact duplicates) over {workers}a "
+      f"{eng.mgr.pool.n_pages}-page pool (lazy admission, chunked prefill, "
+      "preempt/resume under pressure)...")
+finished = {}
+# Decode growth (one page per 8 tokens per sequence) is what
+# oversubscribes the pool.
+rids = [eng.submit(p, max_tokens=24) for p in prompts[:6]]
+for _ in range(3):                               # burst 2 lands mid-serve
+    eng.step(finished)
+rids += [eng.submit(p, max_tokens=24) for p in prompts[6:]]
+done = {**finished, **eng.run()}
 for rid in rids[:4]:
     r = done[rid]
     print(f"  req {rid}: ttft {(r.first_token_at-r.submitted_at)*1e3:6.0f}ms "
@@ -170,12 +153,11 @@ if "autotune" in s["iommu"]:
           f"windows={at['windows']} -> current geometry "
           f"e{s['iommu']['tlb_entries']}.w{s['iommu']['tlb_ways']}."
           f"{s['iommu']['tlb_policy']} (explored: {at['explored']})")
-if bursty:
-    sc = s["sched"]
-    ttft = [done[r].first_token_step - done[r].submitted_step for r in rids]
-    print(f"scheduler: preemptions={sc['preemptions']} "
-          f"resumes={sc['resumes']}; steps-to-first-token "
-          f"mean={np.mean(ttft):.1f} max={max(ttft)}")
+sc = s["sched"]
+ttft = [done[r].first_token_step - done[r].submitted_step for r in rids]
+print(f"scheduler: preemptions={sc['preemptions']} "
+      f"resumes={sc['resumes']}; steps-to-first-token "
+      f"mean={np.mean(ttft):.1f} max={max(ttft)}")
 if args.disagg != "off":
     t = s["transfer"]
     print(f"transfers: {t['transfers']} ({args.disagg}): "

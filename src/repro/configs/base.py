@@ -106,8 +106,8 @@ class ModelConfig:
     prefix_cache_autotune: int = 0
     # Continuous-batching scheduler (core/serving/scheduler.py):
     # per-step token budget for the mixed decode+chunked-prefill batch and
-    # the per-sequence prefill chunk cap. Used when the engine runs with
-    # scheduler="continuous"; validated here so every entry point agrees.
+    # the per-sequence prefill chunk cap. Used by the engine's continuous
+    # loop; validated here so every entry point agrees.
     sched_token_budget: int = 256
     sched_prefill_chunk: int = 64
     # Serving-side translation front-end geometry: the delta-upload cache

@@ -39,6 +39,7 @@ from repro.core.serving.engine import Request, ServingEngine
 from repro.core.serving.scheduler import SchedulerOutput, WaitingSeq
 from repro.core.sva.iommu import IOMMU
 from repro.models import MeshInfo, NO_MESH
+from repro.models.blocks import kv_in_global_pool
 
 
 class DisaggEngine(ServingEngine):
@@ -54,6 +55,15 @@ class DisaggEngine(ServingEngine):
         if n_prefill_slots < 1 or n_decode_slots < 1:
             raise ValueError("need >= 1 prefill and >= 1 decode slot "
                              f"(got {n_prefill_slots}/{n_decode_slots})")
+        if kw.get("offload_mode", "zero_copy") != "zero_copy":
+            raise NotImplementedError(
+                "continuous scheduling requires offload_mode='zero_copy'")
+        if not kv_in_global_pool(cfg):
+            raise NotImplementedError(
+                "continuous scheduling needs all KV state in the shared "
+                "global pool (full-attention archs only): chunked "
+                "prefill cannot reconstruct per-slot ring buffers, "
+                "recurrent states, or cross-KV")
         self.disagg_mode = disagg_mode
         # The transfer fabric's IOMMU (e.g. a 4-entry IOTLB over Sv39Walk)
         # prices migrations; None prices them through the manager's own.
@@ -61,8 +71,7 @@ class DisaggEngine(ServingEngine):
         self.xfer_engine: Optional[KVTransferEngine] = None
         super().__init__(cfg, params,
                          n_slots=n_prefill_slots + n_decode_slots,
-                         max_len=max_len, page_size=page_size, mi=mi,
-                         scheduler="continuous", **kw)
+                         max_len=max_len, page_size=page_size, mi=mi, **kw)
         self.n_prefill_slots = n_prefill_slots
         self.n_decode_slots = n_decode_slots
         prefill_slots = list(range(n_prefill_slots))

@@ -1,37 +1,40 @@
-"""Continuous-batching serving engine over the paged SVA layer.
+"""Serving engine over the paged SVA layer.
 
-Zero-copy offload at serving granularity (the paper's map-don't-copy
-result applied to KV admission):
+Two step loops, picked from what the engine can observe:
 
-  zero_copy  ONE global physical page pool is shared by every batch slot
-             (per KV layer). Admission writes block-table rows (ints) and a
-             single batched/bucketed prefill call scatters KV **directly
-             into the shared pool through those tables** — no per-request
-             cache materialization, no staging copy, no slot-by-slot tree
-             walk. Decode consumes **delta table uploads**: only rows whose
-             tables changed since the last step are re-sent
-             (``PagedKVManager.delta_rows()``), with a full-table upload
-             only on epoch invalidation — the serving-level analogue of a
-             warm IOTLB.
+  continuous  ``offload_mode="zero_copy"`` on a model whose every KV layer
+              lives in the shared global pool
+              (``models.blocks.kv_in_global_pool``). A token-budget
+              :class:`~repro.core.serving.scheduler.Scheduler` composes
+              each step from chunked prefills and a masked decode over the
+              full slot width, admits lazily and preempts under pool
+              pressure. This is the served path.
 
-  copy       The staging baseline (paper Fig. 2's memcpy mode): every
-             admission materializes a fresh single-sequence cache, prefills
-             into it, physically duplicates it, and copies it leaf-by-leaf
-             into the batch cache.
+  fixed-wave  Everything else: the staging-copy baseline
+              (``offload_mode="copy"``, the paper's Fig. 2 memcpy mode:
+              each admission prefills a fresh single-sequence cache,
+              duplicates it and copies it leaf by leaf into the batch
+              cache), and zero-copy models with per-slot state
+              (sliding-window rings, recurrent states, cross-KV, image
+              tokens). Each step admits what fits, prefills it in one
+              padded call and decodes every active slot.
 
-Copy-on-write prefix sharing (zero_copy, full-attention archs): admission
-consults the manager's :class:`~repro.core.sva.kv_manager.PrefixIndex` and
-maps a prompt's already-resident prefix pages via refcount++ — the batched
-prefill then feeds ONLY each prompt's non-shared suffix (fewer tokens per
-admission: a direct throughput win), reading the skipped prefix's KV back
-out of the shared pool (``attention.prefix_context_attention``) and
-scattering through ``write_tables`` whose shared entries are NULLed so a
-shared page is never written. When a decode append lands in a page another
-sequence still maps, the manager queues a CoW page duplication which
-``_apply_cow`` executes device-side (one batched pool-to-pool page copy)
-before the next prefill/decode touches the page. Completed requests leave
-their prompt pages behind as a warm prefix cache (LRU-evicted under page
-pressure).
+Zero-copy admission writes block-table rows (ints) and the prefill
+scatters KV directly into the shared pool through those tables: no
+per-request cache, no staging copy. Decode consumes delta table uploads:
+only rows whose tables changed since the last step are re-sent
+(``PagedKVManager.delta_rows()``), with a full upload only on epoch
+invalidation, the serving-level analogue of a warm IOTLB.
+
+Copy-on-write prefix sharing (continuous loop): admission consults the
+manager's :class:`~repro.core.sva.kv_manager.PrefixIndex` and maps a
+prompt's already-resident prefix pages via refcount++; chunked prefill
+then feeds only the non-shared suffix, reading the prefix's KV back out
+of the pool and scattering through ``write_tables`` whose shared entries
+are NULLed. A decode append into a page another sequence still maps
+queues a CoW duplication, which ``_apply_cow`` executes device-side (one
+batched pool-to-pool page copy) before the next device op. Completed
+requests leave their prompt pages behind as a warm prefix cache.
 
 On a TPU the decode hot path runs through the ragged Pallas kernel
 (kernels/paged_attention, chosen by ``attention.ragged_decode_serves``):
@@ -48,8 +51,7 @@ flush + epoch bump, which this engine absorbs as one full table upload —
 decode outputs are unaffected (placement-invariance, pinned by
 ``tests/test_adaptive_tlb.py``).
 
-CPU-testable with reduced configs; the same engine drives TPU meshes by
-passing a MeshInfo.
+CPU-testable with reduced configs; runs on one device.
 """
 from __future__ import annotations
 
@@ -72,7 +74,7 @@ from repro.core.sva.kv_manager import PagedKVManager
 from repro.models import (MeshInfo, NO_MESH, forward_decode, forward_prefill,
                           init_cache)
 from repro.models import attention as attn
-from repro.models.blocks import MAMBA_KINDS, _sp_mode
+from repro.models.blocks import MAMBA_KINDS, _sp_mode, kv_in_global_pool
 
 # Host spans of one continuous step, written into a running jax.profiler
 # trace on the clock of its device events; with no trace running a span
@@ -258,13 +260,8 @@ class ServingEngine:
                  prefix_sharing: bool = True,
                  record_translation_trace: bool = False,
                  translation_stats: bool = False,
-                 scheduler: str = "fixed",
                  pool_pages: Optional[int] = None,
                  tenants: Optional[Dict[str, dict]] = None):
-        if scheduler not in ("fixed", "continuous"):
-            raise ValueError(f"scheduler={scheduler!r} "
-                             "(expected 'fixed' or 'continuous')")
-        self.scheduler_mode = scheduler
         self.cfg, self.params, self.mi = cfg, params, mi
         self.n_slots, self.max_len, self.page_size = n_slots, max_len, page_size
         self.src_len = src_len
@@ -274,13 +271,11 @@ class ServingEngine:
                     * sum(1 for k in cfg.layer_kinds() if "attn" in k or k == "cross_mlp")
                     * jnp.dtype(cfg.activation_dtype).itemsize)
         self.offload_mode = offload_mode
-        # Prefix sharing needs every stateful layer to live in the shared
-        # global pool: sliding-window rings / recurrent states / cross-KV are
-        # per-slot, so a suffix-only prefill could not reconstruct them.
-        share_kinds = {"attn_mlp", "attn_moe", "attn"}
-        self._can_share = (offload_mode == "zero_copy" and prefix_sharing
-                           and not cfg.is_encdec and not cfg.n_image_tokens
-                           and all(k in share_kinds for k in cfg.layer_kinds()))
+        # Chunked prefill and prefix sharing both rebuild a sequence's state
+        # from the pool alone, so the continuous loop serves exactly the
+        # zero-copy models with no per-slot state.
+        continuous = offload_mode == "zero_copy" and kv_in_global_pool(cfg)
+        self._can_share = continuous and prefix_sharing
         # Adaptive translation front-end (both default-off): IOTLB
         # prefetching on the decode gather stream, and online geometry
         # auto-tuning driven by the live hit-rate signal (which requires
@@ -339,7 +334,7 @@ class ServingEngine:
                                    or prefetch.enabled)
         self.queue: deque = deque()
         self.active: Dict[int, Request] = {}
-        # Continuous mode: requests submitted or preempted but not currently
+        # Continuous loop: requests submitted or preempted but not currently
         # holding a slot (their tokens live in the scheduler's waiting queue).
         self._waiting_reqs: Dict[int, Request] = {}
         self._next_id = 0
@@ -401,26 +396,12 @@ class ServingEngine:
             self._prefill = jax.jit(
                 lambda p, b, c: forward_prefill(cfg, p, b, c, mi))
 
-        # Continuous-batching mode (core/serving/scheduler.py): dense
-        # SequenceBuffer state + a token-budget scheduler composing mixed
-        # decode/chunked-prefill steps, with preemption under pool
-        # pressure. Chunked prefill scatters through write_tables and reads
-        # earlier chunks back via the prefix path, so it needs every
-        # stateful layer in the shared global pool — the same constraint as
-        # prefix sharing, minus the sharing flag itself.
+        # Continuous loop (core/serving/scheduler.py): dense SequenceBuffer
+        # state + a token-budget scheduler composing mixed
+        # decode/chunked-prefill steps, with preemption under pool pressure.
         self.buffer: Optional[SequenceBuffer] = None
         self.sched: Optional[Scheduler] = None
-        if scheduler == "continuous":
-            if offload_mode != "zero_copy":
-                raise NotImplementedError(
-                    "continuous scheduling requires offload_mode='zero_copy'")
-            if (self._exact_prefill or cfg.is_encdec or cfg.n_image_tokens
-                    or not all(k in share_kinds for k in cfg.layer_kinds())):
-                raise NotImplementedError(
-                    "continuous scheduling needs all KV state in the shared "
-                    "global pool (full-attention archs only): chunked "
-                    "prefill cannot reconstruct per-slot ring buffers, "
-                    "recurrent states, or cross-KV")
+        if continuous:
             self.buffer = SequenceBuffer(n_slots,
                                          self.max_pages * page_size)
             self.sched = Scheduler(self.mgr, self.buffer,
@@ -428,6 +409,11 @@ class ServingEngine:
                                    cfg.sched_prefill_chunk,
                                    share_tokens=self._can_share,
                                    on_event=self._trace_event)
+
+    @property
+    def scheduler_mode(self) -> str:
+        """The step loop this engine runs: "continuous" or "fixed"."""
+        return "continuous" if self.sched is not None else "fixed"
 
     # --------------------------------------------------------------- API
     def submit(self, prompt: List[int], max_tokens: int = 16,
@@ -453,7 +439,7 @@ class ServingEngine:
 
     def step(self, finished: Dict[int, Request]) -> None:
         """Run ONE engine step (admission + compute + completion harvest)
-        under the configured scheduler. Benchmarks drive this directly to
+        under the engine's step loop. Benchmarks drive this directly to
         inject arrivals between steps; :meth:`run` is the closed loop."""
         with TraceAnnotation("engine.step", step=self._step_count):
             if self.sched is not None:
@@ -507,67 +493,42 @@ class ServingEngine:
         step performs a full-table upload."""
         self.mgr.invalidate_epoch()
 
-    # --------------------------------------------------------------- admission
+    # --------------------------------------------------- fixed-wave loop
     def _admit(self):
-        if self.offload_mode == "zero_copy":
-            self._apply_cow()   # queued page copies must land before any
-                                # new prefill can recycle their source pages
         admitted: List = []
         while self.queue:
             req = self.queue[0]
             t0 = time.perf_counter()
             st = self.mgr.admit(req.req_id, len(req.prompt), req.max_tokens,
-                                tokens=req.prompt if self._can_share else None,
                                 tenant=req.tenant)
             self.metrics["admit_s"] += time.perf_counter() - t0
             if st is None:
-                break                      # no slot/pages: continuous batching waits
+                break                      # no slot/pages: wait for a release
             self.queue.popleft()
             if self.offload_mode == "copy":
                 self._prefill_into_slot(req, st.slot)
                 self.active[req.req_id] = req
                 continue
             if self.translation_trace is not None:
-                # Listing-1 map pass over the freshly allocated pages
-                # (shared prefix pages were mapped by their provider). The
-                # extended fields — slot + the row's full logical->physical
-                # table — let a replaying prefetcher resolve upcoming pages
-                # the way the hardware reads the page table; replays of the
-                # short ("map", pages) form stay supported (and replay
-                # numbers without prefetching are identical either way).
+                # Listing-1 map pass over the freshly allocated pages. The
+                # extended fields (slot + the row's full logical->physical
+                # table) let a replaying prefetcher resolve upcoming pages
+                # the way the hardware reads the page table.
                 self.translation_trace.append(
-                    ("map", list(st.pages[st.shared_pages:]),
-                     st.slot, list(st.pages)))
-            admitted.append((req, st))
+                    ("map", list(st.pages), st.slot, list(st.pages)))
+            admitted.append(req)
         if not admitted:
             return
-        # Prefill in dependency WAVES: a request whose shared prefix pages
-        # were freshly allocated by another request admitted THIS round must
-        # prefill after its provider (the prefix KV has to be resident in
-        # the pool before a sharer's suffix-only prefill reads it). Wave of
-        # a request = 1 + max wave over the providers of its shared pages.
-        page_wave: Dict[int, int] = {}
-        waves: Dict[int, list] = {}
-        for req, st in admitted:
-            w = 0
-            for pg in st.pages[:st.shared_pages]:
-                if pg in page_wave:
-                    w = max(w, page_wave[pg] + 1)
-            for pg in st.pages[st.shared_pages:]:
-                page_wave[pg] = w
-            waves.setdefault(w, []).append((req, st))
-        for w in sorted(waves):
-            wave = waves[w]
-            if self._exact_prefill:
-                groups: Dict[int, list] = {}
-                for item in wave:
-                    suf = len(item[0].prompt) - item[1].prefill_start
-                    groups.setdefault(suf, []).append(item)
-                for group in groups.values():
-                    self._batched_prefill(group)
-            else:
-                self._batched_prefill(wave)
-        for req, st in admitted:
+        if self._exact_prefill:
+            # Recurrent layers cannot right-pad: batch same-length prompts.
+            groups: Dict[int, list] = {}
+            for req in admitted:
+                groups.setdefault(len(req.prompt), []).append(req)
+            for group in groups.values():
+                self._batched_prefill(group)
+        else:
+            self._batched_prefill(admitted)
+        for req in admitted:
             self.active[req.req_id] = req
 
     def _bucket_len(self, longest: int) -> int:
@@ -578,40 +539,28 @@ class ServingEngine:
             lb *= 2
         return min(lb, self.max_pages * self.page_size)
 
-    def _batched_prefill(self, group):
+    def _batched_prefill(self, group: List[Request]):
         """ONE padded prefill call for all newly admitted requests: KV is
         scattered straight into the shared global pool through the admitted
         rows' block tables. Admission's host->device traffic is the token
-        ids plus int32 table entries — not KV bytes.
-
-        Prefix-shared admissions feed ONLY the non-shared suffix (the
-        bucket is sized on suffix lengths, so a 1000-token prompt with a
-        992-token shared prefix prefills like an 8-token prompt): the
-        skipped prefix's KV is read back from the shared pool, and the
-        scatter runs through ``write_tables`` (shared entries NULLed) so
-        shared pages are never written."""
+        ids plus int32 table entries — not KV bytes."""
         t0 = time.perf_counter()
-        sufs = [len(req.prompt) - st.prefill_start for req, st in group]
-        sharing = any(st.shared_pages for _, st in group)
-        lb = max(sufs) if self._exact_prefill else self._bucket_len(max(sufs))
+        lens = [len(req.prompt) for req in group]
+        lb = max(lens) if self._exact_prefill else self._bucket_len(max(lens))
         nb = 1
         while nb < len(group):
             nb *= 2
         nb = max(min(nb, self.n_slots), len(group))
         tokens = np.zeros((nb, lb), np.int32)
         lengths = np.zeros((nb,), np.int32)
-        prefix = np.zeros((nb,), np.int32)
         slots = np.full((nb,), self.n_slots, np.int32)   # OOB: scatter-dropped
         tables = np.full((nb, self.max_pages), self.mgr.null_page, np.int32)
-        wtables = np.full((nb, self.max_pages), self.mgr.null_page, np.int32)
-        for i, (req, st) in enumerate(group):
-            tokens[i, :sufs[i]] = req.prompt[st.prefill_start:]
-            lengths[i] = sufs[i]
-            prefix[i] = st.prefill_start
+        for i, req in enumerate(group):
+            st = self.mgr.seqs[req.req_id]
+            tokens[i, :lens[i]] = req.prompt
+            lengths[i] = lens[i]
             slots[i] = st.slot
             tables[i] = self.mgr.tables[st.slot]
-            wtables[i] = tables[i]
-            wtables[i, :st.shared_pages] = self.mgr.null_page
         # Admission upload accounting: only the REAL rows' table entries
         # (padding rows exist for jit-key stability, not data movement).
         self.metrics["admit_table_bytes"] += len(group) * self.max_pages * 4
@@ -619,21 +568,16 @@ class ServingEngine:
                  "lengths": jnp.asarray(lengths),
                  "tables": jnp.asarray(tables),
                  "slots": jnp.asarray(slots)}
-        if sharing:
-            # Separate trace: the non-shared path keeps its (cheaper) flash
-            # prefill and its exact numerics.
-            batch["prefix_lens"] = jnp.asarray(prefix)
-            batch["write_tables"] = jnp.asarray(wtables)
         logits, self.cache = self._prefill(self.params, batch, self.cache)
         logits = np.asarray(logits)
         now = time.perf_counter()
-        for i, (req, st) in enumerate(group):
+        for i, req in enumerate(group):
             first = int(np.argmax(logits[i, -1]))
             self.mgr.append_token(req.req_id, first)
             req.first_token_at = now
             req.first_token_step = self._step_count
         self.metrics["prefills"] += 1
-        self.metrics["prefill_tokens"] += sum(sufs)
+        self.metrics["prefill_tokens"] += sum(lens)
         self.metrics["prefill_padded_tokens"] += nb * lb
         self.metrics["prefill_s"] += time.perf_counter() - t0
 
@@ -641,7 +585,7 @@ class ServingEngine:
         cfg = self.cfg
         view = _build_prefill_view(cache, batch["tables"], batch["lengths"])
         fb = {"tokens": batch["tokens"], "lengths": batch["lengths"]}
-        if "prefix_lens" in batch:      # suffix-only prefill (prefix sharing)
+        if "prefix_lens" in batch:      # chunked prefill (continuous loop)
             fb["prefix_lens"] = batch["prefix_lens"]
             fb["write_tables"] = batch["write_tables"]
         nb = batch["tokens"].shape[0]
@@ -790,8 +734,6 @@ class ServingEngine:
                                      for r in self.active]])
         pos = jnp.asarray(kv_len)                       # write/rope position
         if self.offload_mode == "zero_copy":
-            self._apply_cow()       # duplicated pages must exist before the
-                                    # decode writes/reads through new tables
             self._upload_tables()
             if self._translation_stats:
                 # Run this step's page gathers through the IOMMU front-end:
@@ -1009,9 +951,9 @@ class ServingEngine:
         """Optimized HLO of the zero-copy decode step at this engine's
         shapes: what the device runs (e.g. whether the Pallas kernel is in
         it as a ``tpu_custom_call``)."""
-        if self.sched is None or self.offload_mode != "zero_copy":
+        if self.sched is None:
             raise NotImplementedError(
-                "decode_step_hlo needs the continuous zero-copy engine")
+                "decode_step_hlo needs the continuous engine")
         lowered = self._decode_m.lower(
             self.params, jnp.zeros((self.n_slots, 1), jnp.int32),
             jnp.zeros((self.n_slots,), jnp.int32), self._tables_dev,

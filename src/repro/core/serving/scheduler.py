@@ -56,8 +56,8 @@ Same-step sharing note: under chunked prefill the prefix index is fed
 PROGRESSIVELY (pages register as their chunks complete — see
 ``PagedKVManager.register_progress``), so an admission can only match KV
 that is actually resident. Two identical prompts admitted in the SAME
-schedule() call therefore do not share (the fixed engine's admission waves
-would); they share from the next step on.
+schedule() call therefore do not share; they share from the next step
+on.
 """
 from __future__ import annotations
 

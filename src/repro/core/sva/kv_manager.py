@@ -531,7 +531,7 @@ class PagedKVManager:
 
     def __init__(self, n_slots: int, max_pages_per_slot: int, page_size: int,
                  kv_bytes_per_token: int = 0, offload_mode: str = "zero_copy",
-                 layout: Optional[str] = None, prefix_sharing: bool = True,
+                 prefix_sharing: bool = True,
                  prefix_policy: str = "lru", prefix_cap_pages: int = 0,
                  tlb_entries: int = 4096, tlb_policy: str = "lru",
                  tlb_ways: int = 0, tlb_ranges: int = 0,
@@ -542,9 +542,7 @@ class PagedKVManager:
                  sanitize: Optional[bool] = None,
                  tenants: Optional[Dict[str, dict]] = None):
         assert offload_mode in ("zero_copy", "copy")
-        if layout is None:
-            layout = "global" if offload_mode == "zero_copy" else "per_slot"
-        assert layout in ("global", "per_slot")
+        layout = "global" if offload_mode == "zero_copy" else "per_slot"
         # Multi-tenant domains: name -> {quota_pages, quota_prefix_pages,
         # tlb_ways} (every knob optional, 0 = unlimited/shared). Quotas
         # need the one shared pool; nonzero tlb_ways way-partition the

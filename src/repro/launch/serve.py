@@ -37,13 +37,12 @@ def build_engine(cfg: ModelConfig, params, *, slots: int, max_len: int,
                  page_size: int, offload_mode: str = "zero_copy",
                  pool_pages: Optional[int] = None,
                  translation_stats: bool = False) -> ServingEngine:
-    """The served engine: the continuous scheduler over the global
-    zero-copy pool. The staging-copy baseline exists only on the fixed-wave
-    path, so ``offload_mode="copy"`` runs that."""
-    scheduler = "continuous" if offload_mode == "zero_copy" else "fixed"
+    """The served engine. It runs the continuous scheduler over the global
+    zero-copy pool when the model keeps all its KV there, and the
+    fixed-wave loop otherwise (``offload_mode="copy"`` included)."""
     return ServingEngine(cfg, params, n_slots=slots, max_len=max_len,
                          page_size=page_size, offload_mode=offload_mode,
-                         scheduler=scheduler, pool_pages=pool_pages,
+                         pool_pages=pool_pages,
                          translation_stats=translation_stats)
 
 

@@ -30,6 +30,17 @@ ATTN_KINDS = {"attn_mlp", "attn_mlp_local", "attn_moe", "cross_mlp", "attn"}
 MLP_KINDS = {"attn_mlp", "attn_mlp_local", "xattn_mlp", "cross_mlp", "mamba", "attn"}
 MOE_KINDS = {"attn_moe", "mamba_moe"}
 MAMBA_KINDS = {"mamba", "mamba_moe"}
+# Layer kinds whose only serving state is paged KV in the global pool.
+GLOBAL_POOL_KINDS = {"attn_mlp", "attn_moe", "attn"}
+
+
+def kv_in_global_pool(cfg: ModelConfig) -> bool:
+    """True when every piece of a sequence's serving state lives in the
+    shared global page pool: no sliding-window ring, recurrent state,
+    cross-KV or image tokens kept per slot. Only such a model can be
+    prefilled in chunks and share prefix pages."""
+    return (not cfg.is_encdec and not cfg.n_image_tokens
+            and all(k in GLOBAL_POOL_KINDS for k in cfg.layer_kinds()))
 
 
 @dataclass(frozen=True)

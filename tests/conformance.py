@@ -12,10 +12,13 @@ module is the single home for that machinery:
                       tenants, as one immutable value
   pressure_workload   the verified oversubscribed mix (mixed lengths,
                       POOL=8 pages forces preempt/resume on continuous
-                      engines while the fixed engine waits)
+                      engines)
   prefix_workload     the shared-system-prompt mix (CoW + prefix paths)
   make_engine         one constructor for every engine kind:
-                      fixed | continuous | disagg-share | disagg-copy
+                      continuous-ample | continuous | disagg-share |
+                      disagg-copy, and the REFERENCE: the copy-mode engine
+                      (per-slot pools, whole-prompt prefill, staging copy;
+                      an implementation independent of the served loop)
   drive               arrival-faithful driver (requests injected between
                       steps at their tick; the engine never sees the
                       future), tenant-aware
@@ -32,7 +35,7 @@ from repro.core.serving.disagg import DisaggEngine
 from repro.core.serving.engine import ServingEngine
 
 # The verified pressure workload: mixed lengths, tight pool -> the
-# continuous engine preempts and resumes while the fixed engine waits.
+# continuous engine preempts and resumes.
 LENS = (11, 23, 5, 17, 9, 13)
 MAXTOKS = (10, 8, 12, 9, 11, 10)
 POOL = 8
@@ -45,7 +48,10 @@ ARRIVAL_CASES = [
     [0, 0, 9, 9, 0, 4],            # stragglers mid-serve
 ]
 
-ENGINE_KINDS = ("fixed", "continuous", "disagg-share", "disagg-copy")
+ENGINE_KINDS = ("continuous-ample", "continuous", "disagg-share",
+                "disagg-copy")
+# Ground truth every engine kind must reproduce token-for-token.
+REFERENCE = "copy"
 
 
 @dataclass(frozen=True)
@@ -101,20 +107,24 @@ def prefix_workload(vocab: int, n: int = 6, max_tokens: int = 6,
 def make_engine(cfg, params, kind: str, n_slots: int = 4, max_len: int = 64,
                 page_size: int = 8, tenants: Optional[Dict[str, dict]] = None,
                 **engine_kw):
-    """One constructor for every engine kind (see ENGINE_KINDS).
-    ``disagg-*`` splits ``n_slots`` evenly into prefill/decode workers so
-    every kind serves at equal total slot width."""
-    if kind not in ENGINE_KINDS:
-        raise ValueError(f"kind={kind!r} (expected one of {ENGINE_KINDS})")
+    """One constructor for every engine kind (see ENGINE_KINDS) and the
+    REFERENCE. ``disagg-*`` splits ``n_slots`` evenly into prefill/decode
+    workers so every kind serves at equal total slot width; the two
+    colocated continuous kinds differ only in the pool their caller
+    passes (``continuous-ample``: ``pool_pages=None``)."""
+    if kind not in ENGINE_KINDS + (REFERENCE,):
+        raise ValueError(f"kind={kind!r} (expected one of {ENGINE_KINDS} "
+                         f"or {REFERENCE!r})")
     if kind.startswith("disagg-"):
         return DisaggEngine(cfg, params, n_prefill_slots=n_slots // 2,
                             n_decode_slots=n_slots - n_slots // 2,
                             max_len=max_len, page_size=page_size,
                             disagg_mode=kind.split("-", 1)[1],
                             tenants=tenants, **engine_kw)
+    if kind == REFERENCE:
+        engine_kw["offload_mode"] = "copy"
     return ServingEngine(cfg, params, n_slots=n_slots, max_len=max_len,
-                         page_size=page_size, scheduler=kind,
-                         tenants=tenants, **engine_kw)
+                         page_size=page_size, tenants=tenants, **engine_kw)
 
 
 def drive(eng, workload: Workload):

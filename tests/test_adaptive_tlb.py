@@ -258,15 +258,18 @@ def test_autotune_mid_serve_resize_is_bit_identical(key):
         eng = ServingEngine(c, params, n_slots=2, max_len=64, page_size=8)
         rids = [eng.submit(p, max_tokens=6) for p in prompts]
         done = eng.run()
-        return [done[r].out_tokens for r in rids], eng.stats()
+        # a switch in the run's last step has no later step to absorb it
+        unabsorbed = int(eng.mgr.epoch != eng._epoch_seen)
+        return [done[r].out_tokens for r in rids], eng.stats(), unabsorbed
 
-    out_static, s_static = serve(0)
-    out_tuned, s_tuned = serve(1, candidates=((2, 0, "lru"), (64, 0, "lru")))
+    out_static, s_static, _ = serve(0)
+    out_tuned, s_tuned, unabsorbed = serve(
+        1, candidates=((2, 0, "lru"), (64, 0, "lru")))
     assert out_tuned == out_static                 # placement-invariant
     at = s_tuned["iommu"]["autotune"]
     assert at["switches"] >= 1                     # it really resized
     assert s_tuned["iommu"]["epoch"] >= at["switches"]
-    assert s_tuned["table_uploads_full"] >= 1 + at["switches"]
+    assert s_tuned["table_uploads_full"] == 1 + at["switches"] - unabsorbed
     assert "autotune" not in s_static["iommu"]
 
 

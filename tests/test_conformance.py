@@ -1,7 +1,7 @@
 """Cross-engine conformance matrix over the shared harness
-(tests/conformance.py): every engine kind (fixed, continuous,
+(tests/conformance.py): every engine kind (continuous-ample, continuous,
 disagg-share, disagg-copy) x tenancy mode (off, single-tenant deployment,
-two tenants) must reproduce the unconstrained fixed engine's outputs
+two tenants) must reproduce the copy-mode reference engine's outputs
 token-for-token on the canonical pressure workload.
 
 The single-tenant column is the PR's compatibility acceptance: a
@@ -16,7 +16,7 @@ from repro.configs import get_config, reduce_for_smoke
 from repro.configs.deployment import DeploymentConfig, TenantSpec
 from repro.models import init_params
 from tests.conformance import (ARRIVAL_CASES, ENGINE_KINDS, POOL,
-                               assert_bit_identical, make_engine,
+                               REFERENCE, assert_bit_identical, make_engine,
                                pressure_workload, serve)
 
 TENANCIES = ("off", "single", "two")
@@ -31,10 +31,10 @@ def setup():
 
 @pytest.fixture(scope="module")
 def ref(setup):
-    """Ground truth, computed once: unconstrained fixed engine,
-    untenanted."""
+    """Ground truth, computed once: the copy-mode reference engine,
+    untenanted (copy mode refuses tenants)."""
     cfg, params = setup
-    outs, _, _ = serve(cfg, params, "fixed",
+    outs, _, _ = serve(cfg, params, REFERENCE,
                        pressure_workload(cfg.vocab_size))
     return outs
 
@@ -43,7 +43,7 @@ def _tenancy(cfg, kind, tenancy, n_req=6):
     """(compiled cfg, engine tenants dict, per-request tenant names,
     pool_pages) for one matrix cell. Quotas equal the whole pool and
     partitioning stays off, so tenancy adds bookkeeping, never behavior."""
-    pool = POOL if kind != "fixed" else None
+    pool = POOL if kind != "continuous-ample" else None
     if tenancy == "off":
         return cfg, None, None, pool
     if tenancy == "single":
@@ -90,7 +90,7 @@ def test_assert_bit_identical_entrypoint(setup):
     kinds, one workload."""
     cfg, params = setup
     wl = pressure_workload(cfg.vocab_size)
-    assert_bit_identical(make_engine(cfg, params, "fixed"),
+    assert_bit_identical(make_engine(cfg, params, REFERENCE),
                          make_engine(cfg, params, "continuous",
                                      pool_pages=POOL),
                          wl)
@@ -120,6 +120,6 @@ def test_assert_bit_identical_detects_divergence(setup):
             return getattr(self._eng, name)
 
     with pytest.raises(AssertionError):
-        assert_bit_identical(make_engine(cfg, params, "fixed"),
-                             Clipped(make_engine(cfg, params, "fixed")),
+        assert_bit_identical(make_engine(cfg, params, REFERENCE),
+                             Clipped(make_engine(cfg, params, REFERENCE)),
                              wl)

@@ -23,7 +23,7 @@ from repro.core.sva.iommu import (IOMMU, CountingWalk, Sv39Walk, TLBConfig)
 from repro.core.sva.kv_manager import PagedKVManager
 from repro.core.sva.page_pool import OutOfPages
 from repro.models import init_params
-from tests.conformance import (ARRIVAL_CASES, POOL, Workload,
+from tests.conformance import (ARRIVAL_CASES, POOL, REFERENCE, Workload,
                                pressure_workload, serve)
 
 try:
@@ -43,8 +43,8 @@ def setup():
 
 # The shared pressure workload (tests/conformance.py): mixed lengths,
 # tight pool -> transfers defer, decode-side preemption fires. The
-# unconstrained fixed engine at the same total width is the ground truth
-# every disaggregation policy must reproduce (serve(cfg, params, "fixed")).
+# copy-mode reference engine at the same total width is the ground truth
+# every disaggregation policy must reproduce (serve(cfg, params, REFERENCE)).
 def _serve_disagg(cfg, params, mode, workload, **engine_kw):
     return serve(cfg, params, f"disagg-{mode}", workload, **engine_kw)
 
@@ -165,10 +165,10 @@ def test_disagg_engine_validation(setup):
 @pytest.mark.parametrize("mode", ["share", "copy"])
 def test_disagg_bit_identical_ample_pool(setup, mode):
     """No pool pressure: prefill-worker chunking + migration + decode-
-    worker masking reproduces the fixed engine token-for-token."""
+    worker masking reproduces the reference engine token-for-token."""
     cfg, params = setup
     wl = pressure_workload(cfg.vocab_size)
-    ref, _, _ = serve(cfg, params, "fixed", wl)
+    ref, _, _ = serve(cfg, params, REFERENCE, wl)
     outs, eng, done = _serve_disagg(cfg, params, mode, wl)
     assert outs == ref
     s = eng.stats()
@@ -182,10 +182,10 @@ def test_disagg_bit_identical_ample_pool(setup, mode):
 @pytest.mark.parametrize("mode", ["share", "copy"])
 def test_disagg_bit_identical_under_pressure(setup, mode):
     """Oversubscribed pool: transfers defer/cancel, prefills and decodes
-    preempt — and outputs STILL match the unconstrained fixed engine."""
+    preempt — and outputs STILL match the reference engine."""
     cfg, params = setup
     wl = pressure_workload(cfg.vocab_size)
-    ref, _, _ = serve(cfg, params, "fixed", wl)
+    ref, _, _ = serve(cfg, params, REFERENCE, wl)
     outs, eng, _ = _serve_disagg(cfg, params, mode, wl, pool_pages=POOL)
     assert outs == ref
     assert eng.stats()["disagg"]["transfers"] >= 1
@@ -195,7 +195,8 @@ def test_disagg_bit_identical_under_pressure(setup, mode):
 @pytest.mark.parametrize("arrivals", ARRIVAL_CASES)
 def test_disagg_interleaving_bit_identity(setup, mode, arrivals):
     cfg, params = setup
-    ref, _, _ = serve(cfg, params, "fixed", pressure_workload(cfg.vocab_size))
+    ref, _, _ = serve(cfg, params, REFERENCE,
+                      pressure_workload(cfg.vocab_size))
     outs, _, _ = _serve_disagg(
         cfg, params, mode,
         pressure_workload(cfg.vocab_size, arrivals=arrivals),
@@ -206,7 +207,7 @@ def test_disagg_interleaving_bit_identity(setup, mode, arrivals):
 def _check_disagg_interleaving(reqs, seed):
     """(prompt_len, max_tokens, arrival_gap) requests: the pool-constrained
     disaggregated engine (share mode, the zero-copy path with the most
-    aliasing hazards) is bit-identical to the fixed engine on them —
+    aliasing hazards) is bit-identical to the reference engine on them —
     svasan watching throughout."""
     import jax
     cfg = dataclasses.replace(
@@ -218,7 +219,7 @@ def _check_disagg_interleaving(reqs, seed):
                     for n, _, _ in reqs)
     maxtoks = tuple(m for _, m, _ in reqs)
     arrivals = tuple(np.cumsum([g for _, _, g in reqs]).tolist())
-    ref, _, _ = serve(cfg, params, "fixed", Workload(prompts, maxtoks))
+    ref, _, _ = serve(cfg, params, REFERENCE, Workload(prompts, maxtoks))
     outs, eng, _ = _serve_disagg(
         cfg, params, "share",
         Workload(prompts, maxtoks, arrivals=arrivals), pool_pages=POOL)
@@ -242,8 +243,8 @@ if HAVE_HYPOTHESIS:
            st.integers(0, 2 ** 31 - 1))
     def test_disagg_interleaving_property(reqs, seed):
         """Any (prompt_len, max_tokens, arrival_gap) interleaving: the
-        pool-constrained disaggregated engine is bit-identical to the fixed
-        engine on the same requests."""
+        pool-constrained disaggregated engine is bit-identical to the
+        reference engine on the same requests."""
         _check_disagg_interleaving(reqs, seed)
 
 
@@ -271,7 +272,8 @@ def test_preempt_during_pending_transfer(setup):
     prefill completes — without this, the pump migrates a torn-down
     sequence. Copy mode under the straggler arrivals forces the race."""
     cfg, params = setup
-    ref, _, _ = serve(cfg, params, "fixed", pressure_workload(cfg.vocab_size))
+    ref, _, _ = serve(cfg, params, REFERENCE,
+                      pressure_workload(cfg.vocab_size))
     outs, eng, _ = _serve_disagg(
         cfg, params, "copy",
         pressure_workload(cfg.vocab_size, arrivals=[0, 0, 9, 9, 0, 4]),

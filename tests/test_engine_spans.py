@@ -29,8 +29,7 @@ def engine():
     cfg = dataclasses.replace(reduce_for_smoke(get_config("llama3.2-1b")),
                               sched_token_budget=256, sched_prefill_chunk=64)
     params = init_params(cfg, jax.random.key(0))
-    return ServingEngine(cfg, params, n_slots=4, max_len=256, page_size=8,
-                         scheduler="continuous")
+    return ServingEngine(cfg, params, n_slots=4, max_len=256, page_size=8)
 
 
 @pytest.fixture(scope="module")

@@ -24,7 +24,7 @@ from repro.core.sva.iommu import (IOMMU, CountingWalk, IsolationError,
                                   TLBConfig)
 from repro.core.sva.kv_manager import CapacityError, PagedKVManager
 from repro.models import init_params
-from tests.conformance import Workload, pressure_workload, serve
+from tests.conformance import REFERENCE, Workload, pressure_workload, serve
 
 try:
     import hypothesis.strategies as st
@@ -226,12 +226,12 @@ def test_partition_validation():
 def test_quota_preemption_bit_identical(setup):
     """Pool is AMPLE but tenant a's quota is tight: decode growth pushes a
     over quota, the scheduler sheds a's newest sequence (sparing the
-    oldest — no thrash), and outputs still match the unconstrained fixed
-    engine token-for-token."""
+    oldest — no thrash), and outputs still match the untenanted copy-mode
+    reference engine token-for-token."""
     cfg, params = setup
     base = pressure_workload(cfg.vocab_size)
     prompts, maxtoks = base.prompts[:4], (10, 10, 8, 8)
-    ref, _, _ = serve(cfg, params, "fixed", Workload(prompts, maxtoks))
+    ref, _, _ = serve(cfg, params, REFERENCE, Workload(prompts, maxtoks))
     outs, eng, _ = serve(cfg, params, "continuous",
                          Workload(prompts, maxtoks,
                                   tenants=("a", "a", "b", "b")),

@@ -1,8 +1,8 @@
 """Continuous-batching scheduler: token-budget steps, chunked prefill,
-preemption/resume under pool pressure — and the PR's core contract: the
-continuous engine's outputs are BIT-IDENTICAL to the fixed engine's for
-every workload and arrival interleaving (scheduling policy never changes
-tokens), including across preempt/resume round-trips.
+preemption/resume under pool pressure — and the core contract: the
+continuous engine's outputs are BIT-IDENTICAL to the copy-mode reference
+engine's for every workload and arrival interleaving (scheduling policy
+never changes tokens), including across preempt/resume round-trips.
 
 Workload constants, the arrival-faithful driver, and the bit-identity
 assertion live in tests/conformance.py (shared with test_disagg.py,
@@ -22,7 +22,7 @@ from repro.core.serving.sequence_buffer import SequenceBuffer
 from repro.core.sva.iommu import IOMMU, CountingWalk, TLBConfig
 from repro.core.sva.kv_manager import PagedKVManager
 from repro.models import init_params
-from tests.conformance import (ARRIVAL_CASES, POOL, Workload,
+from tests.conformance import (ARRIVAL_CASES, POOL, REFERENCE, Workload,
                                pressure_workload, serve)
 
 try:
@@ -87,11 +87,13 @@ def test_pool_pages_validation():
 
 def test_continuous_matches_fixed_ample_pool(setup):
     """No pool pressure: continuous (chunked prefill + masked decode)
-    reproduces the fixed engine token-for-token."""
+    reproduces the fixed-wave copy-mode reference token-for-token."""
     cfg, params = setup
     wl = pressure_workload(cfg.vocab_size)
-    fixed, _, _ = serve(cfg, params, "fixed", wl)
+    fixed, ref_eng, _ = serve(cfg, params, REFERENCE, wl)
     cont, eng, _ = serve(cfg, params, "continuous", wl)
+    assert ref_eng.scheduler_mode == "fixed"
+    assert eng.scheduler_mode == "continuous"
     assert cont == fixed
     assert eng.stats()["sched"]["preemptions"] == 0
 
@@ -103,7 +105,7 @@ def test_preempt_resume_bit_identical_under_pressure(setup):
     re-injected, max_tokens is rebased)."""
     cfg, params = setup
     wl = pressure_workload(cfg.vocab_size)
-    ref, _, _ = serve(cfg, params, "fixed", wl)
+    ref, _, _ = serve(cfg, params, REFERENCE, wl)
     cont, eng, _ = serve(cfg, params, "continuous", wl, pool_pages=POOL)
     s = eng.stats()
     assert s["sched"]["preemptions"] >= 1
@@ -131,7 +133,8 @@ def test_preemption_svasan_clean(setup):
 @pytest.mark.parametrize("arrivals", ARRIVAL_CASES)
 def test_interleaving_bit_identity(setup, arrivals):
     cfg, params = setup
-    ref, _, _ = serve(cfg, params, "fixed", pressure_workload(cfg.vocab_size))
+    ref, _, _ = serve(cfg, params, REFERENCE,
+                      pressure_workload(cfg.vocab_size))
     cont, _, _ = serve(cfg, params, "continuous",
                        pressure_workload(cfg.vocab_size, arrivals=arrivals),
                        pool_pages=POOL)
@@ -140,7 +143,7 @@ def test_interleaving_bit_identity(setup, arrivals):
 
 def _check_interleaving(reqs, seed):
     """(prompt_len, max_tokens, arrival_gap) requests: the pool-constrained
-    continuous engine is bit-identical to the fixed engine on them."""
+    continuous engine is bit-identical to the reference engine on them."""
     import jax
     cfg = reduce_for_smoke(get_config("llama3.2-1b"))
     params = init_params(cfg, jax.random.key(0))
@@ -150,7 +153,7 @@ def _check_interleaving(reqs, seed):
                     for n, _, _ in reqs)
     maxtoks = tuple(m for _, m, _ in reqs)
     arrivals = tuple(np.cumsum([g for _, _, g in reqs]).tolist())
-    ref, _, _ = serve(cfg, params, "fixed", Workload(prompts, maxtoks))
+    ref, _, _ = serve(cfg, params, REFERENCE, Workload(prompts, maxtoks))
     cont, _, _ = serve(cfg, params, "continuous",
                        Workload(prompts, maxtoks, arrivals=arrivals),
                        pool_pages=POOL)
@@ -173,8 +176,8 @@ if HAVE_HYPOTHESIS:
            st.integers(0, 2 ** 31 - 1))
     def test_interleaving_property(reqs, seed):
         """Any (prompt_len, max_tokens, arrival_gap) interleaving: the
-        pool-constrained continuous engine is bit-identical to the fixed
-        engine on the same requests."""
+        pool-constrained continuous engine is bit-identical to the
+        reference engine on the same requests."""
         _check_interleaving(reqs, seed)
 
 

@@ -137,11 +137,36 @@ def test_continuous_engine_pool_in_layer_carry(key, scan_blocks):
     ref_cfg = replace(base, scan_blocks=False)
     expected = [_manual_tokens(ref_cfg, params, p) for p in prompts]
     cfg = replace(base, scan_blocks=scan_blocks, sched_prefill_chunk=8)
-    eng = ServingEngine(cfg, params, n_slots=3, max_len=64, page_size=8,
-                        scheduler="continuous")
+    eng = ServingEngine(cfg, params, n_slots=3, max_len=64, page_size=8)
+    assert eng.scheduler_mode == "continuous"
     rids = [eng.submit(p, max_tokens=6) for p in prompts]
     done = eng.run()
     assert [done[r].out_tokens for r in rids] == expected
+
+
+@pytest.mark.parametrize("arch,engine,offload,loop", [
+    ("llama3.2-1b", "colocated", "zero_copy", "continuous"),
+    ("llama3.2-1b", "colocated", "copy", "fixed"),
+    ("gemma2-2b", "colocated", "zero_copy", "fixed"),
+    ("gemma2-2b", "disagg", "zero_copy", None),
+])
+def test_engine_picks_step_loop(key, arch, engine, offload, loop):
+    """The step loop follows from what the engine can observe: continuous
+    when the KV lives in the global zero-copy pool, fixed-wave otherwise
+    (the copy baseline; gemma2's sliding-window rings are per slot). The
+    disaggregated engine exists only on the continuous loop."""
+    from repro.core.serving.disagg import DisaggEngine
+    cfg = reduce_for_smoke(get_config(arch))
+    params = init_params(cfg, key)
+    if engine == "disagg":
+        with pytest.raises(NotImplementedError, match="global pool"):
+            DisaggEngine(cfg, params, n_prefill_slots=1, n_decode_slots=1,
+                         max_len=32, page_size=8)
+        return
+    eng = ServingEngine(cfg, params, n_slots=2, max_len=32, page_size=8,
+                        offload_mode=offload)
+    assert eng.scheduler_mode == loop
+    assert (eng.sched is not None) == (loop == "continuous")
 
 
 def test_engine_copy_mode_same_tokens_more_copies(key):
@@ -313,26 +338,35 @@ SYS = list(range(200, 216))                      # 2 full pages @ page_size 8
 
 
 def _share_engine_outputs(cfg, params, prompts, share, n=6):
+    """Serve ``prompts[0]`` alone for one token, then the rest together.
+    The first request's KV is resident and indexed before the others are
+    admitted, and since its only token is never written, its partial tail
+    page stays in the warm cache too."""
     eng = ServingEngine(cfg, params, n_slots=4, max_len=64, page_size=8,
                         prefix_sharing=share)
-    rids = [eng.submit(p, max_tokens=n) for p in prompts]
+    rids = [eng.submit(prompts[0], max_tokens=1)]
     done = eng.run()
+    rids += [eng.submit(p, max_tokens=n) for p in prompts[1:]]
+    done.update(eng.run())
     return [done[r].out_tokens for r in rids], eng.stats()
 
 
 def test_prefix_sharing_bit_identical_to_unshared(key):
     """Acceptance: shared-prefix admissions prefill only the non-shared
     suffix (prefill_tokens_saved > 0, pages shared > 0) and decode outputs
-    are bit-identical to unshared serving."""
+    are bit-identical to unshared serving. The two copies of the first
+    prompt both map its cached partial tail page, so the first of them to
+    write there duplicates it (CoW)."""
     cfg = reduce_for_smoke(get_config("llama3.2-1b"))
     params = init_params(cfg, key)
-    prompts = [SYS + [5, 9, 2], SYS + [7, 7], SYS + [5, 9, 2], [42, 43]]
+    dup = SYS + [5, 9, 2]
+    prompts = [dup, SYS + [7, 7], dup, dup, [42, 43]]
     got_s, ss = _share_engine_outputs(cfg, params, prompts, True)
     got_u, su = _share_engine_outputs(cfg, params, prompts, False)
     assert got_s == got_u                        # placement never changes tokens
     assert ss["prefill_tokens_saved"] > 0
     assert ss["prefix"]["pages_shared"] > 0
-    assert ss["shared_admissions"] == 2
+    assert ss["shared_admissions"] == 3          # the three SYS prompts
     assert ss["cow_page_copies"] > 0             # identical prompt diverged
     assert su["prefill_tokens_saved"] == 0 and "prefix" not in su
     assert ss["sva"]["bytes_copied"] == 0        # still zero-copy admission
@@ -403,25 +437,27 @@ def test_prefix_cache_lru_eviction_under_pressure():
 def test_engine_pallas_decode_backend_matches_jax(key, monkeypatch):
     """The ragged paged-decode kernel on the hot path (interpret mode on
     CPU, forced through ``attention.ragged_decode_serves``) produces the
-    same tokens as the pure-JAX gather path, on the fixed-wave and the
-    continuous engine."""
+    same tokens as the pure-JAX gather path, on the fixed-wave engine
+    (gemma2-2b: sliding-window layers keep it there) and the continuous
+    engine (llama3.2-1b)."""
     from repro.models import attention as attn
-    cfg = reduce_for_smoke(get_config("llama3.2-1b"))
-    params = init_params(cfg, key)
     prompts = [SYS + [5, 9, 2], [11, 4], SYS + [7]]
     serves = attn.ragged_decode_serves
 
-    def run(kernel, scheduler):
+    def run(kernel, cfg, params):
         monkeypatch.setattr(attn, "ragged_decode_serves",
                             attn.is_global_layout if kernel else serves)
-        eng = ServingEngine(cfg, params, n_slots=2, max_len=32, page_size=8,
-                            scheduler=scheduler)
+        eng = ServingEngine(cfg, params, n_slots=2, max_len=32, page_size=8)
         rids = [eng.submit(p, max_tokens=4) for p in prompts]
         done = eng.run()
-        return [done[r].out_tokens for r in rids]
+        return eng.scheduler_mode, [done[r].out_tokens for r in rids]
 
-    for scheduler in ("fixed", "continuous"):
-        assert run(True, scheduler) == run(False, scheduler)
+    for arch, mode in (("gemma2-2b", "fixed"), ("llama3.2-1b", "continuous")):
+        cfg = reduce_for_smoke(get_config(arch))
+        params = init_params(cfg, key)
+        kernel = run(True, cfg, params)
+        assert kernel[0] == mode
+        assert kernel == run(False, cfg, params)
 
 
 def _prefix_policy_scenario(policy):

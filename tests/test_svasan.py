@@ -19,7 +19,6 @@ def mk_manager(**kw):
     kw.setdefault("n_slots", 4)
     kw.setdefault("max_pages_per_slot", 8)
     kw.setdefault("page_size", 4)
-    kw.setdefault("layout", "global")
     kw.setdefault("sanitize", True)
     return PagedKVManager(**kw)
 
